@@ -13,7 +13,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.hlo_analysis import V5E, analyze, roofline_terms
+from repro.distributed.hlo_analysis import V5E_KIND, analyze, roofline_terms
 
 
 def _enet_flops(decomposed: bool, batch: int = 1, hw: int = 512):
@@ -42,7 +42,7 @@ def run(csv: bool = False) -> list[tuple]:
                  f"{cut:.1f} (paper cycle cut: 87.8)"))
     rows.append(("enet_hlo.flop_speedup_x", us,
                  f"{naive.flops/dec.flops:.2f} (paper: 8.2)"))
-    tn, td = roofline_terms(naive), roofline_terms(dec)
+    tn, td = roofline_terms(naive, V5E_KIND), roofline_terms(dec, V5E_KIND)
     for k in ("compute_s", "memory_s"):
         rows.append((f"enet_hlo.naive_{k}", us, f"{tn[k]*1e3:.3f} ms"))
         rows.append((f"enet_hlo.dec_{k}", us, f"{td[k]*1e3:.3f} ms"))

@@ -85,6 +85,9 @@ def main(argv: list[str] | None = None) -> None:
                          "times (default xla; add pallas on accelerators)")
     ns = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (enet_roofline, fig10_enet_speedup,
                             fig11_dilated_layers, fig12_transposed_layers,
                             kernel_bench, mixed_precision, roofline,

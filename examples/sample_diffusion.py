@@ -45,6 +45,9 @@ def main(argv: list[str] | None = None) -> None:
     small = ns.smoke or (ns.backend == "pallas"
                          and jax.default_backend() == "cpu")
     widths, hw = ((8, 8), 4) if small else ((32, 16, 8), 4)
+    if small and not ns.smoke:
+        print("[sample_diffusion] pallas on a CPU backend runs interpret "
+              "mode: smoke widths, not the demo ones")
     step_list = [int(s) for s in ns.steps.split(",")]
 
     params = unet_decoder.init_denoiser_params(

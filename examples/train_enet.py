@@ -23,8 +23,9 @@ import numpy as np
 
 from repro.data import SegDataPipeline
 from repro.launch import train_recipes
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import enet
-from repro.optim import adamw_init, adamw_update, cosine_schedule
+from repro.optim import cosine_schedule
 
 
 def main() -> None:
@@ -57,55 +58,31 @@ def main() -> None:
         args.hw = min(args.hw, 16)
         args.log_every = 1
 
+    enable_compile_cache()
     params = enet.init_params(jax.random.PRNGKey(0), args.classes)
     pipe = SegDataPipeline(args.batch, hw=args.hw, classes=args.classes)
+    cd = "bf16" if args.dtype == "bf16" else None
 
-    if args.dtype == "bf16":
-        # the mixed-precision recipe owns the optimizer + loss scaling
-        state = train_recipes.init_state(params)
-        recipe_step = train_recipes.make_train_step(
-            "enet", backend=args.backend, decomposed=decomposed,
-            compute_dtype="bf16", lr=args.lr, weight_decay=1e-4)
-
-        def train_step(params, opt, image, label, lr):
-            nonlocal state
-            state = state._replace(params=params, opt=opt)
-            state, m = recipe_step(state,
-                                   {"image": image, "label": label})
-            return state.params, state.opt, m["loss"], m["grad_norm"]
-
-        opt = state.opt
-    else:
-        opt = adamw_init(params)
-
-        @jax.jit
-        def train_step(params, opt, image, label, lr):
-            def loss_fn(p):
-                logits = enet.forward(p, image, decomposed=decomposed,
-                                      backend=args.backend)
-                logp = jax.nn.log_softmax(logits.astype(jnp.float32),
-                                          axis=-1)
-                nll = -jnp.take_along_axis(logp, label[..., None], axis=-1)
-                return jnp.mean(nll)
-
-            loss, grads = jax.value_and_grad(loss_fn)(params)
-            params, opt, gnorm = adamw_update(grads, opt, params, lr=lr,
-                                              weight_decay=1e-4)
-            return params, opt, loss, gnorm
+    # the recipe: fp32 masters + AdamW + dynamic loss scaling; bf16 compute
+    # when asked (DESIGN.md §12)
+    state = train_recipes.init_state(params)
+    train_step = train_recipes.make_train_step(
+        "enet", backend=args.backend, decomposed=decomposed,
+        compute_dtype=cd, weight_decay=1e-4,
+        lr=lambda t: cosine_schedule(t, args.steps // 10, args.steps,
+                                     args.lr))
 
     losses = []
     for step in range(args.steps):
         b = pipe.batch_at(step)
-        lr = cosine_schedule(jnp.int32(step), args.steps // 10, args.steps,
-                             args.lr)
         t0 = time.time()
-        params, opt, loss, gnorm = train_step(
-            params, opt, jnp.asarray(b["image"]), jnp.asarray(b["label"]), lr)
-        losses.append(float(loss))
+        state, m = train_step(state, {"image": jnp.asarray(b["image"]),
+                                      "label": jnp.asarray(b["label"])})
+        losses.append(float(m["loss"]))
         if step % args.log_every == 0:
-            print(f"step {step:4d} loss {float(loss):.4f} "
-                  f"gnorm {float(gnorm):.3f} dt {(time.time()-t0)*1e3:.0f}ms",
-                  flush=True)
+            print(f"step {step:4d} loss {losses[-1]:.4f} "
+                  f"gnorm {float(m['grad_norm']):.3f} "
+                  f"dt {(time.time()-t0)*1e3:.0f}ms", flush=True)
         if not np.isfinite(losses[-1]):
             raise SystemExit(f"non-finite loss at step {step}")
 
@@ -114,8 +91,7 @@ def main() -> None:
           f"({'improved' if last < first else 'NOT improved'})")
     # pixel accuracy on a fresh batch
     b = pipe.batch_at(10_000)
-    cd = "bf16" if args.dtype == "bf16" else None
-    pred = jnp.argmax(enet.forward(params, jnp.asarray(b["image"]),
+    pred = jnp.argmax(enet.forward(state.params, jnp.asarray(b["image"]),
                                    decomposed=decomposed,
                                    backend=args.backend,
                                    compute_dtype=cd), -1)

@@ -352,15 +352,34 @@ def analyze(hlo_text: str) -> HLOAnalysis:
 
 # ------------------------------------------------------------ roofline ----
 
-V5E = {
-    "flops_bf16": 197e12,   # per chip
-    "hbm_gbps": 819e9,      # per chip
-    "ici_gbps": 50e9,       # per link
+#: ``device_kind`` JAX reports for a TPU v5e chip
+V5E_KIND = "TPU v5 lite"
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.  Source:
+#: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+#: 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (four links of 50 GB/s).
+PEAKS = {
+    V5E_KIND: {
+        "flops_bf16": 197e12,   # per chip
+        "hbm_gbps": 819e9,      # per chip
+        "ici_gbps": 50e9,       # per link
+    },
 }
 
 
-def roofline_terms(a: HLOAnalysis, hw: dict = V5E) -> dict[str, float]:
-    """Per-chip time (s) if each resource were the only bottleneck."""
+def peaks(device_kind: str) -> dict[str, float]:
+    """Published peaks of ``device_kind``; an unknown device is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
+
+def roofline_terms(a: HLOAnalysis, device_kind: str) -> dict[str, float]:
+    """Per-chip time (s) if each resource were the only bottleneck, on the
+    chip ``device_kind`` names (a key of :data:`PEAKS`)."""
+    hw = peaks(device_kind)
     return {
         "compute_s": a.flops / hw["flops_bf16"],
         "memory_s": a.hbm_bytes / hw["hbm_gbps"],

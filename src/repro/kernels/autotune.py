@@ -57,6 +57,14 @@ def autotune_enabled() -> bool:
     return os.environ.get("REPRO_AUTOTUNE", "").lower() in ("1", "true", "on")
 
 
+def table_disabled() -> bool:
+    """``$REPRO_AUTOTUNE=off``: every geometry gets ``DEFAULT_TILES`` and
+    the on-disk table is never read — a run built only from the checkout
+    (``chip_smoke.py``) depends on no machine-local state."""
+    return os.environ.get("REPRO_AUTOTUNE", "").lower() in ("0", "false",
+                                                            "off")
+
+
 def _device_kind() -> str:
     try:
         kind = jax.devices()[0].device_kind
@@ -317,11 +325,14 @@ def get_tiles(kind: str, x_shape: tuple, w_shape: tuple, *, stride: int = 1,
               epilogue=None) -> tuple[int, int]:
     """Resolve the tile shape for one geometry: mem -> disk -> tune/defaults.
 
+    ``REPRO_AUTOTUNE=off`` skips the lookup and returns the defaults.
     Only tunes on a full miss when ``REPRO_AUTOTUNE=1`` — the default is a
     pure lookup so cold paths (tests, first-run UX) stay deterministic and
     cheap; the table is populated by CI / ``kernel_bench`` runs and shipped
     via the CI cache.
     """
+    if table_disabled():
+        return DEFAULT_TILES
     key = make_key(kind, x_shape, w_shape, stride=stride, dilation=dilation,
                    dtype=dtype, padding=padding,
                    output_padding=output_padding, epilogue=epilogue)
@@ -342,4 +353,4 @@ def get_tiles(kind: str, x_shape: tuple, w_shape: tuple, *, stride: int = 1,
 
 __all__ = ["DEFAULT_TILES", "POLICY_TOP", "get_tiles", "tune", "make_key",
            "candidates", "cache_path", "clear_memory_cache",
-           "autotune_enabled"]
+           "autotune_enabled", "table_disabled"]

@@ -8,18 +8,22 @@ Rectangular kernels (``kh != kw`` — ENet's 5x1/1x5 asymmetric pair) are
 first-class: the tap loops, pads and halo are all per-dim.
 
 Tiling (per grid step): one batch element, ``TH`` output rows x full output
-width, one ``TC``-wide ``Cout`` tile.  The input row halo (``kh - stride``
-rows) is assembled *without overlapping BlockSpecs* by passing the input
-twice — the current row tile and the next row tile — and concatenating in
-VMEM (standard Pallas halo idiom).
+width, one ``TC``-wide ``Cout`` tile.  The padded input is first split into
+``stride**2`` phase planes (a layout op, the identity for ``stride == 1``),
+so every tap of a strided conv reads a unit-stride window of one plane.  The
+row halo (``(kh - 1) // stride`` phase rows) is assembled *without
+overlapping BlockSpecs* by passing the input twice — the current row tile
+and the next row tile — and concatenating in VMEM (standard Pallas halo
+idiom).
 
 An optional fused epilogue (:mod:`repro.kernels.epilogue`, DESIGN.md §7) —
 folded BN scale/shift, PReLU, residual add — is applied to the fp32
 accumulator tile while it is still in VMEM, removing up to three elementwise
 HBM passes per convolution.
 
-VMEM per step ~ x_tile(2 * s*TH * Wp * Cin) + w(kh*kw*Cin*TC) + out(TH*W*TC),
-sized well under a v5e core's VMEM for every shape used in this repo.  The
+VMEM per step ~ x_tile(2 * s*s * TH * Wq * Cin) + w(kh*kw*Cin*TC) +
+out(TH*W*TC), each padded to the (sublane, 128-lane) register tile; the
+kernel compiles with ``tiling_policy.VMEM_LIMIT_BYTES`` of scoped VMEM.  The
 grid runs the row stream innermost with ``dimension_semantics`` declared, so
 Mosaic's pipeliner double-buffers the input halo pair (next tile's DMA
 overlaps the current tile's MXU work) while the weight tile stays resident
@@ -43,6 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import tiling_policy
 from repro.kernels.epilogue import EpilogueSpec, apply_tile, pack_args
 from repro.kernels.util import resolve_interpret
 
@@ -50,23 +55,27 @@ _NO_EP = EpilogueSpec()
 
 
 def _conv_kernel(x_cur, x_nxt, w, *rest, spec: EpilogueSpec, th: int,
-                 kh: int, kw: int, stride: int, w_out: int):
-    """One (batch, row-tile, cout-tile) grid step."""
+                 kh: int, kw: int, stride: int, w_out: int, halo: int):
+    """One (batch, row-tile, cout-tile) grid step.
+
+    The input arrives split into ``stride**2`` phase planes, so tap
+    ``(dy, dx)`` of a strided conv is a unit-stride window of plane
+    ``(dy % s, dx % s)`` at offset ``(dy // s, dx // s)`` — Mosaic lowers
+    unit-stride value slices but not strided ones.
+    """
     out = rest[-1]
     ep_refs = rest[:-1]
     s = stride
-    halo = kh - s
-    # assemble the input window: s*TH rows + halo rows from the next tile
+    # assemble the window: TH phase rows + halo rows from the next tile
     xw = x_cur[0]
     if halo > 0:
-        xw = jnp.concatenate([xw, x_nxt[0][:halo]], axis=0)
+        xw = jnp.concatenate([xw, x_nxt[0][:, :halo]], axis=1)
     cin = xw.shape[-1]
     acc = jnp.zeros((th * w_out, out.shape[-1]), jnp.float32)
     for dy in range(kh):
         for dx in range(kw):
-            # output row t reads input row s*t + dy; col c reads s*c + dx
-            rows = xw[dy : dy + s * (th - 1) + 1 : s,
-                      dx : dx + s * (w_out - 1) + 1 : s, :]
+            oy, ox = dy // s, dx // s
+            rows = xw[(dy % s) * s + dx % s, oy:oy + th, ox:ox + w_out, :]
             acc += jax.lax.dot_general(
                 rows.reshape(th * w_out, cin), w[dy, dx],
                 (((1,), (0,)), ((), ())),
@@ -140,28 +149,30 @@ def _conv2d_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
     h_out = (h + ph[0] + ph[1] - kh) // s + 1
     w_out = (w_in + pw[0] + pw[1] - kw) // s + 1
 
+    # phase-split geometry: output row t, tap dy reads padded input row
+    # s*t + dy = s*(t + dy//s) + dy%s, i.e. row t + dy//s of phase dy%s
+    halo = (kh - 1) // s
     th = min(th, h_out)
-    # the halo (kh - s rows) is served from the *next* row tile, which holds
-    # s*th rows — keep th large enough that one tile covers it (tiny inputs)
-    th = max(th, math.ceil(max(kh - s, 0) / s))
+    th = max(th, halo)      # the next row tile must cover the halo
     n_row_tiles = math.ceil(h_out / th)
     h_out_p = n_row_tiles * th
     tc = min(tc, cout)
     n_cout_tiles = math.ceil(cout / tc)
     cout_p = n_cout_tiles * tc
 
-    # pad input so every tile (incl. the +1 halo tile) reads in-bounds:
-    # rows needed: s*h_out_p + (kh - s) for tiles, plus one extra halo tile.
-    # (when VALID windows don't consume the whole input, the "needed" extent
-    # is smaller than what's there — clamp at 0; excess rows/cols are simply
-    # never read by any block)
-    rows_needed = s * h_out_p + max(kh - s, 0) + s * th
-    cols_needed = s * (w_out - 1) + kw
+    # phase rows: every row tile plus one extra tile that the next-tile
+    # BlockSpec reads for the halo; phase cols: w_out plus the column halo
+    rows_q = h_out_p + th
+    cols_q = w_out + (kw - 1) // s
     xp = jnp.pad(
         x,
-        ((0, 0), (ph[0], max(rows_needed - h - ph[0], 0)),
-         (pw[0], max(cols_needed - w_in - pw[0], 0)), (0, 0)),
-    )
+        ((0, 0), (ph[0], max(s * rows_q - h - ph[0], 0)),
+         (pw[0], max(s * cols_q - w_in - pw[0], 0)), (0, 0)),
+    )[:, :s * rows_q, :s * cols_q, :]
+    # (N, s*Rq, s*Cq, Cin) -> (N, s*s, Rq, Cq, Cin): a layout op (identity
+    # for s == 1) that turns every strided tap into a unit-stride window
+    xp = xp.reshape(n, rows_q, s, cols_q, s, cin).transpose(0, 2, 4, 1, 3, 5)
+    xp = xp.reshape(n, s * s, rows_q, cols_q, cin)
     wp = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, cout_p - cout)))
 
     # grid order (batch, cout tile, row tile): the row stream is innermost,
@@ -169,10 +180,10 @@ def _conv2d_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
     # pair advances by one block per step) while the weight tile's block
     # index is unchanged across the whole inner stream and stays resident
     grid = (n, n_cout_tiles, n_row_tiles)
-    x_spec_cur = pl.BlockSpec((1, s * th, cols_needed, cin),
-                              lambda b, c, i: (b, i, 0, 0))
-    x_spec_nxt = pl.BlockSpec((1, s * th, cols_needed, cin),
-                              lambda b, c, i: (b, i + 1, 0, 0))
+    x_spec_cur = pl.BlockSpec((1, s * s, th, cols_q, cin),
+                              lambda b, c, i: (b, 0, i, 0, 0))
+    x_spec_nxt = pl.BlockSpec((1, s * s, th, cols_q, cin),
+                              lambda b, c, i: (b, 0, i + 1, 0, 0))
     w_spec = pl.BlockSpec((kh, kw, cin, tc), lambda b, c, i: (0, 0, 0, c))
     out_spec = pl.BlockSpec((1, th, w_out, tc), lambda b, c, i: (b, i, 0, c))
 
@@ -194,7 +205,7 @@ def _conv2d_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
 
     out = pl.pallas_call(
         functools.partial(_conv_kernel, spec=spec, th=th, kh=kh, kw=kw,
-                          stride=s, w_out=w_out),
+                          stride=s, w_out=w_out, halo=halo),
         grid=grid,
         in_specs=[x_spec_cur, x_spec_nxt, w_spec, *ep_specs],
         out_specs=out_spec,
@@ -202,8 +213,9 @@ def _conv2d_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
         # batch/cout steps are independent; the row stream is sequential so
         # Mosaic's pipeliner overlaps each tile's DMA with the previous
         # tile's MXU work (double-buffered VMEM streams)
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=tiling_policy.VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(xp, xp, wp, *ep_in)
     return out[:, :h_out, :, :cout]

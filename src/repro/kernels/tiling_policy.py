@@ -7,11 +7,17 @@ top few (plus ``DEFAULT_TILES``) are ever run:
 * **VMEM footprint** — each candidate's per-grid-step working set, assembled
   from the same block shapes the kernels declare (`conv2d.py`,
   `transposed_conv.py`), doubled for the pipeline's double-buffered
-  input/weight/output streams, plus the fp32 accumulator.  The footprint is
-  dtype-aware (bf16 halves the streamed bytes) and epilogue-aware (a fused
-  residual streams a second output-shaped block; channel vectors ride along
-  as fp32 rows).  Candidates that overflow the budget score ``inf`` — they
-  would spill or fail to fit, so they are never worth timing.
+  input/weight/output streams, plus the in-kernel halo window and the fp32
+  accumulator.  Every buffer is counted as VMEM holds it: its last two dims
+  padded to the (sublane, 128-lane) register tile — (8, 128) fp32, (16, 128)
+  bf16 — so a 3-channel stem block costs 128 lanes, as the compiler counts
+  it.  The footprint is dtype-aware (bf16 halves the streamed bytes) and
+  epilogue-aware (a fused residual streams a second output-shaped block;
+  channel vectors ride along as fp32 rows).  Candidates that overflow the
+  budget score ``inf`` — they would spill or fail to fit, so they are never
+  worth timing.  The kernels themselves compile with a raised scoped-VMEM
+  limit (:data:`VMEM_LIMIT_BYTES`), which also covers what the count
+  leaves out.
 * **MXU occupancy** — each grid step issues GEMMs of shape
   ``(th * w_out, cin) x (cin, tc)``.  Lanes pad to 128, sublanes pack by
   dtype (8 fp32 / 16 bf16 rows per tile), so narrow ``tc`` or a flattened
@@ -36,9 +42,18 @@ import os
 
 import jax.numpy as jnp
 
-#: ~16 MiB of VMEM per TPU core; leave headroom for compiler scratch and
-#: semaphores so a "fits" verdict survives lowering.
+#: the default scoped VMEM of a v5e kernel is 16 MiB; leave headroom for
+#: compiler scratch and semaphores so a "fits" verdict survives lowering.
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+
+#: scoped VMEM limit every engine kernel compiles with: half of a v5e
+#: core's 128 MiB, the rest left to the compiler's own buffers.  Above the
+#: 16 MiB default because the blocks are not all a kernel holds there: the
+#: ENet-512 stem's blocks alone take 24.2 MiB (3 channels on 128 lanes),
+#: and the per-tap slices and, at HIGHEST matmul precision, the operand
+#: splits of each fp32 contraction come on top — 19.8 MiB for the 3x3
+#: 4-channel backward of ENet-512's train step, whose blocks count 8.5 MiB.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 #: MXU lane width — the last-dim tiling quantum on TPU.
 LANES = 128
@@ -55,19 +70,25 @@ def sublanes(dtype) -> int:
     return max(8 * (4 // max(itemsize(dtype), 1)), 8)
 
 
-def _ep_extra(spec, out_elems: int, isz: int) -> int:
+def padded_bytes(shape, dtype) -> int:
+    """Bytes of one VMEM buffer: the last two dims padded to the register
+    tile (``sublanes(dtype)`` x 128 lanes), leading dims as they are."""
+    *lead, rows, cols = (1,) * max(2 - len(shape), 0) + tuple(shape)
+    rows = math.ceil(rows / sublanes(dtype)) * sublanes(dtype)
+    cols = math.ceil(cols / LANES) * LANES
+    return math.prod(lead) * rows * cols * itemsize(dtype)
+
+
+def _ep_extra(spec, out_bytes: int, tc: int) -> int:
     """Streamed bytes a fused epilogue adds per grid step.
 
-    Channel vectors (scale/shift/alpha) travel as fp32 ``(1, tc)`` rows —
-    negligible but counted; a residual streams a full output-shaped block in
-    the output dtype.
+    Channel vectors (scale/shift/alpha) travel as fp32 ``(1, tc)`` rows; a
+    residual streams a full output-shaped block in the output dtype.
     """
     if spec is None or spec.empty:
         return 0
-    extra = 0
-    for name in spec.slots:
-        extra += out_elems * isz if name == "residual" else 0
-    return extra
+    return sum(out_bytes if name == "residual"
+               else padded_bytes((1, tc), jnp.float32) for name in spec.slots)
 
 
 def _dense_geometry(x_shape, w_shape, stride, padding):
@@ -79,6 +100,8 @@ def _dense_geometry(x_shape, w_shape, stride, padding):
         pw = ((kw - 1) // 2, kw // 2)
     elif padding == "VALID":
         ph = pw = (0, 0)
+    elif isinstance(padding, tuple):    # the kernel's resolved (ph, pw)
+        ph, pw = padding
     else:
         ph = pw = (padding, padding)
     h_out = (h + ph[0] + ph[1] - kh) // stride + 1
@@ -100,24 +123,29 @@ def footprint_bytes(kind: str, x_shape, w_shape, th: int, tc: int, *,
     """Per-grid-step VMEM working set of one ``(th, tc)`` candidate (bytes).
 
     Mirrors the kernels' BlockSpecs: double-buffered input halo pair +
-    weight tile + output tile (x2 for the pipeline), epilogue operands, and
-    the fp32 accumulator.  Dilated geometries are scored as the dense kernel
-    on the phase-batched layout they actually run.
+    weight tile + output tile (x2 for the pipeline), epilogue operands, the
+    assembled halo window and the fp32 accumulator, each padded to the
+    register tile (:func:`padded_bytes`).  Dilated geometries are scored as
+    the dense kernel on the phase-batched layout they actually run.
     """
-    isz = itemsize(dtype)
+    f32 = jnp.float32
     if kind == "dilated":
         x_shape = _phase_batched(x_shape, dilation)
         stride, padding = 1, None   # classes fold the stride out
     if kind in ("dense", "dilated"):
         _, h_out, w_out, cin, cout, kh, kw = _dense_geometry(
             x_shape, w_shape, stride, padding)
-        th_e = max(min(th, h_out), math.ceil(max(kh - stride, 0) / stride))
+        s = stride
+        halo = (kh - 1) // s                # phase rows (conv2d.py)
+        th_e = max(min(th, h_out), halo)
         tc_e = min(tc, cout)
-        cols = stride * (w_out - 1) + kw
-        x_block = stride * th_e * cols * cin          # x_cur; x_nxt doubles it
-        w_block = kh * kw * cin * tc_e
-        out_block = th_e * w_out * tc_e
-        acc = th_e * w_out * tc_e * 4
+        cols = w_out + (kw - 1) // s
+        x_block = padded_bytes((s * s, th_e, cols, cin), dtype)
+        window = padded_bytes((s * s, th_e + halo, cols, cin), dtype) \
+            if halo else 0
+        w_block = padded_bytes((kh, kw, cin, tc_e), dtype)
+        out_block = padded_bytes((th_e, w_out, tc_e), dtype)
+        acc = padded_bytes((th_e * w_out, tc_e), f32)
     else:       # tconv: parity-plane kernel (transposed_conv.py)
         from repro.core import transposed as tr
         from repro.kernels.transposed_conv import parity_schedule
@@ -137,13 +165,15 @@ def footprint_bytes(kind: str, x_shape, w_shape, th: int, tc: int, *,
         th_e = max(min(th, hb), halo)
         tc_e = min(tc, cout)
         cols = max(wb + halo, w_in + shift)
-        x_block = th_e * cols * cin
-        w_block = k * k * cin * tc_e
-        out_block = s * s * th_e * wb * tc_e
-        acc = s * s * th_e * wb * tc_e * 4
-    streamed = (2 * x_block + w_block + out_block) * isz
-    streamed += _ep_extra(epilogue, out_block, isz)
-    return 2 * streamed + acc       # x2: the pipeline double-buffers streams
+        x_block = padded_bytes((th_e, cols, cin), dtype)
+        window = padded_bytes((th_e + halo, cols, cin), dtype) if halo else 0
+        w_block = padded_bytes((k, k, cin, tc_e), dtype)
+        out_block = padded_bytes((s * s, th_e, wb, tc_e), dtype)
+        acc = s * s * padded_bytes((th_e * wb, tc_e), f32)
+    streamed = 2 * x_block + w_block + out_block
+    streamed += _ep_extra(epilogue, out_block, tc_e)
+    # x2: the pipeline double-buffers streams
+    return 2 * streamed + window + acc
 
 
 def mxu_occupancy(kind: str, x_shape, w_shape, th: int, tc: int, *,
@@ -162,7 +192,7 @@ def mxu_occupancy(kind: str, x_shape, w_shape, th: int, tc: int, *,
     if kind in ("dense", "dilated"):
         _, h_out, w_out, _, cout, kh, _ = _dense_geometry(
             x_shape, w_shape, stride, padding)
-        th_e = max(min(th, h_out), math.ceil(max(kh - stride, 0) / stride))
+        th_e = max(min(th, h_out), (kh - 1) // stride)
         rows = th_e * w_out
     else:
         from repro.core import transposed as tr
@@ -269,6 +299,5 @@ def top_candidates(kind: str, x_shape, w_shape, cands, *, top: int = 3,
     return [c for c in cands if c in keep]   # candidate order == sweep order
 
 
-__all__ = ["VMEM_BUDGET_BYTES", "LANES", "itemsize", "sublanes",
-           "footprint_bytes", "mxu_occupancy", "rank", "top_candidates",
-           "sweep_forced"]
+__all__ = ["VMEM_BUDGET_BYTES", "VMEM_LIMIT_BYTES", "LANES", "itemsize",
+           "sublanes", "padded_bytes", "footprint_bytes", "mxu_occupancy", "rank", "top_candidates", "sweep_forced"]

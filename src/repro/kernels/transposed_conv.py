@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import tiling_policy
 from repro.kernels.epilogue import (EpilogueSpec, apply_reference, apply_tile,
                                     pack_args)
 from repro.kernels.util import resolve_interpret
@@ -242,8 +243,9 @@ def _tconv_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
             (n, s * s, n_row_tiles * th, wb, cout_p), x.dtype),
         # batch/cout steps independent; sequential row stream -> Mosaic
         # overlaps each tile's DMA with the previous tile's MXU work
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=tiling_policy.VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(xp, xp, wp, *ep_in)
 

@@ -63,7 +63,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         cost = compiled.cost_analysis()
         txt = compiled.as_text()
         analysis = ha.analyze(txt)
-        terms = ha.roofline_terms(analysis)
+        terms = ha.roofline_terms(analysis, ha.V5E_KIND)  # target chip
 
         counts = cfg.param_counts()
         cell = SHAPES[shape_name]

@@ -19,20 +19,29 @@ CPU-scale smoke (any launch loop picks the mesh up automatically):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``: shardings propagate
+    through GSPMD, and eager ops may mix meshed and single-device arrays
+    (the serving lanes write one slot at a time).  ``make_mesh``'s own
+    default makes the axes ``Explicit``, which refuses that mix."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_smoke_mesh(devices: int | None = None, model: int = 2):
     """Small mesh over however many (possibly fake) devices exist."""
     n = devices or len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_train_mesh(devices: int | None = None):
@@ -42,4 +51,4 @@ def make_train_mesh(devices: int | None = None):
     would just replicate, so the whole device count goes to data.
     """
     n = devices or len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return auto_mesh((n,), ("data",))

@@ -58,7 +58,7 @@ def run_variant(arch: str, shape: str, variant: str, *, multi_pod: bool,
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     a = ha.analyze(compiled.as_text())
-    terms = ha.roofline_terms(a)
+    terms = ha.roofline_terms(a, ha.V5E_KIND)    # the pods' chip
     rec = {
         "arch": arch, "shape": shape, "variant": variant,
         "mesh": "2x16x16" if multi_pod else "16x16",

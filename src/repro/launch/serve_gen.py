@@ -78,6 +78,7 @@ from repro.distributed import sharding as shd
 from repro.distributed.fault_tolerance import (FailureInjector,
                                                StragglerWatchdog)
 from repro.kernels.util import canon_dtype
+from repro.launch.mesh import auto_mesh
 from repro.launch.steps import (DDIM_T_MAX, ddim_timesteps,
                                 make_gen_scan_step)
 from repro.models import dcgan, unet_decoder
@@ -1116,7 +1117,7 @@ class GenServer:
                     f"snapshot took a {shape} mesh but only "
                     f"{len(jax.devices())} devices exist; pass mesh= to "
                     f"restore() to reshard")
-            cfg["mesh"] = jax.make_mesh(shape, tuple(mesh_cfg["axes"]))
+            cfg["mesh"] = auto_mesh(shape, tuple(mesh_cfg["axes"]))
         kw = dict(cfg, snapshot_dir=directory)
         kw.update(overrides)
         server = cls(**kw)
@@ -1178,6 +1179,12 @@ class GenServer:
     def request(self, rid: int) -> GenRequest:
         """Any submitted request by id (whatever its lifecycle state)."""
         return self._requests[rid]
+
+    def lane_devices(self) -> dict[str, set]:
+        """Devices holding each built lane's slot state (the diffusion image
+        batch, the DCGAN latents): where a meshed lane really runs."""
+        return {w: (lane.x if lane.kind == "diffusion" else lane.z)
+                .sharding.device_set for w, lane in self._lanes.items()}
 
     def stats(self) -> dict[str, float]:
         wall = (time.perf_counter() - self._t0) if self._t0 else 0.0
@@ -1284,6 +1291,9 @@ def main() -> None:
     ns = ap.parse_args()
 
     from repro.core import calibrate as cal
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     scan: int | str = ns.scan_steps if ns.scan_steps == "auto" \
         else int(ns.scan_steps)
@@ -1302,6 +1312,9 @@ def main() -> None:
         kw.update(mesh=make_smoke_mesh(ns.devices), spatial=ns.spatial)
     if ns.smoke or (ns.backend == "pallas" and jax.default_backend() == "cpu"):
         # interpret-mode pallas needs tiny widths to stay tractable on CPU
+        if not ns.smoke:
+            print("[serve_gen] pallas on a CPU backend runs interpret mode: "
+                  "serving smoke widths, not the canonical ones")
         kw.update(unet_widths=(8, 8), unet_hw=4, dcgan_nz=16, dcgan_ngf=4)
     cache = cal.default_cache_path()
     if cache.exists():          # host-grounded admission estimates when a

@@ -34,11 +34,10 @@ held to convergence bounds instead.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed import compression as _compression
@@ -78,7 +77,7 @@ def _loss_fn(model: str, *, backend: str, decomposed: bool,
              interpret: bool | None, compute_dtype: str | None):
     if model == "enet":
         kw = dict(backend=backend, decomposed=decomposed,
-                  compute_dtype=compute_dtype)
+                  interpret=interpret, compute_dtype=compute_dtype)
         return functools.partial(_seg_loss, enet.forward, **kw)
     if model == "espnet":
         kw = dict(backend=backend, decomposed=decomposed,
@@ -104,14 +103,17 @@ def make_train_step(model: str, *, backend: str = "xla",
                     decomposed: bool = True, interpret: bool | None = None,
                     compute_dtype: str | None = None,
                     scaler: DynamicLossScale | None = None,
-                    lr: float = 1e-3, weight_decay: float = 1e-4):
+                    lr: float | Callable[[jax.Array], jax.Array] = 1e-3,
+                    weight_decay: float = 1e-4):
     """Jitted ``step(state, batch) -> (state', metrics)`` for one recipe.
 
     ``batch`` is ``{"image", "label"}`` for the segmentation recipes and
-    ``{"z", "target"}`` for the generator.  Metrics: ``loss`` (unscaled,
-    fp32), ``grad_norm`` (of the *applied* gradients; 0 on a skipped
-    step), ``scale`` (loss scale after the update), ``skipped`` (1.0 when
-    non-finite gradients suppressed the update).
+    ``{"z", "target"}`` for the generator.  ``lr`` is a constant or a
+    schedule called with the optimizer's step count (0 on the first step).
+    Metrics: ``loss`` (unscaled, fp32), ``grad_norm`` (of the *applied*
+    gradients; 0 on a skipped step), ``scale`` (loss scale after the
+    update), ``skipped`` (1.0 when non-finite gradients suppressed the
+    update).
     """
     scaler = scaler or DynamicLossScale()
     loss_fn = _loss_fn(model, backend=backend, decomposed=decomposed,
@@ -131,8 +133,9 @@ def make_train_step(model: str, *, backend: str = "xla",
         # grads before the update, then discard the whole update anyway
         zeros = jax.tree_util.tree_map(jnp.zeros_like, grads)
         safe = select_tree(finite, grads, zeros)
+        step_lr = lr(state.opt.step) if callable(lr) else jnp.float32(lr)
         new_params, new_opt, gnorm = adamw_update(
-            safe, state.opt, state.params, lr=jnp.float32(lr),
+            safe, state.opt, state.params, lr=step_lr,
             weight_decay=weight_decay)
         new_params = select_tree(finite, new_params, state.params)
         new_opt = select_tree(finite, new_opt, state.opt)
@@ -205,8 +208,10 @@ def make_sharded_train_step(model: str, mesh, *, virtual_shards: int = 8,
     * ``grad_transport="bf16"`` — bf16 stacks on the wire (2x smaller
       collective in the compiled HLO); convergence-bounded, not bitwise.
 
-    XLA backend only: per-chunk gradients vmap over the model forward, and
-    the Pallas kernels' ``custom_vjp`` has no batching rule.
+    XLA backend only: each device takes its chunks' gradients one after
+    another with ``lax.map`` inside ``shard_map``, and the bitwise
+    mesh-invariance is pinned for the XLA engine alone — the Pallas kernels
+    have not been run under ``shard_map``.
     """
     if backend != "xla":
         raise ValueError(
@@ -218,9 +223,9 @@ def make_sharded_train_step(model: str, mesh, *, virtual_shards: int = 8,
     axis = axes if len(axes) > 1 else axes[0]
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(), P(axis)), out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
     def chunk_grads(params, scale_state, chunks):
         # per-chunk scaled-loss gradients, SEQUENTIALLY per device: lax.map
         # compiles one per-chunk graph applied to every chunk, so the chunk

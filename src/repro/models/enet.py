@@ -74,25 +74,25 @@ def _bottleneck_init(key, c: int, kind: str = "regular", cin: int | None = None,
 
 def _bottleneck(p: dict, x: jax.Array, kind: str, c: int, dilation: int = 1,
                 decomposed: bool = True, strategy: str = "batched",
-                backend: str = "xla", compute_dtype=None) -> jax.Array:
+                backend: str = "xla", interpret: bool | None = None,
+                compute_dtype=None) -> jax.Array:
     """kind: regular | dilated | asym | down | up."""
-    cd = compute_dtype
+    kw = dict(backend=backend, interpret=interpret, compute_dtype=compute_dtype)
     s1, b1 = fold_bn(p["bn1"])
     ep1 = dict(epilogue=_EP_BN_ACT, scale=s1, shift=b1, alpha=p["a1"])
     if kind == "down":
-        h = conv2d(x, p["reduce"], stride=2, padding=0, backend=backend,
-                   compute_dtype=cd, **ep1)
+        h = conv2d(x, p["reduce"], stride=2, padding=0, **kw, **ep1)
         skip = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
                                      (1, 2, 2, 1), "VALID")
         pad_c = c - x.shape[-1]
         skip = jnp.pad(skip, ((0, 0), (0, 0), (0, 0), (0, pad_c)))
     elif kind == "up":
-        h = conv2d(x, p["reduce"], backend=backend, compute_dtype=cd, **ep1)
-        skip = conv2d(x, p["skip"], backend=backend, compute_dtype=cd)
+        h = conv2d(x, p["reduce"], **kw, **ep1)
+        skip = conv2d(x, p["skip"], **kw)
         # nearest-neighbour unpool stand-in for max-unpool indices
         skip = jnp.repeat(jnp.repeat(skip, 2, axis=1), 2, axis=2)
     else:
-        h = conv2d(x, p["reduce"], backend=backend, compute_dtype=cd, **ep1)
+        h = conv2d(x, p["reduce"], **kw, **ep1)
         skip = x
 
     s2, b2 = fold_bn(p["bn2"])
@@ -100,24 +100,21 @@ def _bottleneck(p: dict, x: jax.Array, kind: str, c: int, dilation: int = 1,
     if kind == "asym":
         # 5x1/1x5 pair: rectangular kernels through the engine's dense path
         # (SAME pads one dim only); BN2/PReLU fuse into the second conv
-        h = conv2d(h, p["conv_v"], backend=backend, compute_dtype=cd)
-        h = conv2d(h, p["conv_h"], backend=backend, compute_dtype=cd, **ep2)
+        h = conv2d(h, p["conv_v"], **kw)
+        h = conv2d(h, p["conv_h"], **kw, **ep2)
     elif kind == "up":
         h = conv2d(h, p["deconv"], stride=2, transposed=True,
-                   output_padding=1, decomposed=decomposed, backend=backend,
-                   compute_dtype=cd, **ep2)
+                   output_padding=1, decomposed=decomposed, **kw, **ep2)
     elif kind == "dilated":
         h = conv2d(h, p["conv"], dilation=dilation, decomposed=decomposed,
-                   strategy=strategy, backend=backend, compute_dtype=cd,
-                   **ep2)
+                   strategy=strategy, **kw, **ep2)
     else:
-        h = conv2d(h, p["conv"], backend=backend, compute_dtype=cd, **ep2)
+        h = conv2d(h, p["conv"], **kw, **ep2)
 
     # expand projection closes the bottleneck: BN3, +skip, PReLU — one pass
     s3, b3 = fold_bn(p["bn3"])
-    return conv2d(h, p["expand"], backend=backend, epilogue=_EP_BN_RES_ACT,
-                  scale=s3, shift=b3, alpha=p["a3"], residual=skip,
-                  compute_dtype=cd)
+    return conv2d(h, p["expand"], epilogue=_EP_BN_RES_ACT, scale=s3,
+                  shift=b3, alpha=p["a3"], residual=skip, **kw)
 
 
 # stage layout: (name, kind, channels, dilation)
@@ -148,9 +145,10 @@ def init_params(key, num_classes: int = 19, dtype=jnp.float32) -> dict:
 
 @functools.partial(jax.jit,
                    static_argnames=("decomposed", "strategy", "backend",
-                                    "compute_dtype"))
+                                    "interpret", "compute_dtype"))
 def forward(params: dict, x: jax.Array, decomposed: bool = True,
             strategy: str = "batched", backend: str = "xla",
+            interpret: bool | None = None,
             compute_dtype: str | None = None) -> jax.Array:
     """x: (N, H, W, 3) -> logits (N, H, W, classes).
 
@@ -160,6 +158,8 @@ def forward(params: dict, x: jax.Array, decomposed: bool = True,
     asymmetric pair — so a pallas forward is all-pallas, with BN/PReLU/
     residual epilogues fused into the kernels (DESIGN.md §7).  The whole
     forward is differentiable on both backends (DESIGN.md §6).
+    ``interpret`` is the Pallas interpret-mode override (``None``: interpret
+    on CPU only; ``False`` insists on compiled kernels).
 
     ``compute_dtype`` (e.g. ``"bf16"``; static — pass the string form) casts
     the input once and every conv per-layer, so activations flow in the
@@ -171,34 +171,26 @@ def forward(params: dict, x: jax.Array, decomposed: bool = True,
         from repro.kernels.util import canon_dtype
 
         x = x.astype(canon_dtype(cd))
-    h = conv2d(x, params["initial"], stride=2, backend=backend,
-               compute_dtype=cd)
+    kw = dict(backend=backend, interpret=interpret, compute_dtype=cd)
+    bkw = dict(decomposed=decomposed, **kw)
+    h = conv2d(x, params["initial"], stride=2, **kw)
     pool = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
                                  (1, 2, 2, 1), "VALID")
     h = jnp.concatenate([h, pool], axis=-1)          # (N, H/2, W/2, 16)
 
-    h = _bottleneck(params["b1_0"], h, "down", 64, backend=backend,
-                    compute_dtype=cd)
+    h = _bottleneck(params["b1_0"], h, "down", 64, **bkw)
     for i in range(1, 5):
-        h = _bottleneck(params[f"b1_{i}"], h, "regular", 64, backend=backend,
-                        compute_dtype=cd)
-    h = _bottleneck(params["b2_0"], h, "down", 128, backend=backend,
-                    compute_dtype=cd)
+        h = _bottleneck(params[f"b1_{i}"], h, "regular", 64, **bkw)
+    h = _bottleneck(params["b2_0"], h, "down", 128, **bkw)
     for stage in (2, 3):
         for i, (kind, d) in enumerate(_STAGE2, start=1):
             k = {"reg": "regular", "dil": "dilated", "asym": "asym"}[kind]
             h = _bottleneck(params[f"b{stage}_{i}"], h, k, 128, dilation=d,
-                            decomposed=decomposed, strategy=strategy,
-                            backend=backend, compute_dtype=cd)
-    h = _bottleneck(params["b4_0"], h, "up", 64, decomposed=decomposed,
-                    backend=backend, compute_dtype=cd)
+                            strategy=strategy, **bkw)
+    h = _bottleneck(params["b4_0"], h, "up", 64, **bkw)
     for i in range(1, 3):
-        h = _bottleneck(params[f"b4_{i}"], h, "regular", 64, backend=backend,
-                        compute_dtype=cd)
-    h = _bottleneck(params["b5_0"], h, "up", 16, decomposed=decomposed,
-                    backend=backend, compute_dtype=cd)
-    h = _bottleneck(params["b5_1"], h, "regular", 16, backend=backend,
-                    compute_dtype=cd)
+        h = _bottleneck(params[f"b4_{i}"], h, "regular", 64, **bkw)
+    h = _bottleneck(params["b5_0"], h, "up", 16, **bkw)
+    h = _bottleneck(params["b5_1"], h, "regular", 16, **bkw)
     return conv2d(h, params["fullconv"], stride=2, transposed=True,
-                  output_padding=1, decomposed=decomposed, backend=backend,
-                  compute_dtype=cd)
+                  output_padding=1, decomposed=decomposed, **kw)

@@ -13,10 +13,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import compression as C
+from repro.launch.mesh import make_train_mesh
 
 #: leaf shapes chosen to stress the wire format: odd length, scalar,
 #: zero-size, word-aligned, and > one word
@@ -121,10 +121,10 @@ def _stacks(chunks=8, seed=4):
 
 
 def _reduce_on(nd, stacks, transport):
-    mesh = jax.make_mesh((nd,), ("data",))
-    fn = shard_map(
+    mesh = make_train_mesh(nd)
+    fn = jax.shard_map(
         lambda s: C.mesh_allreduce(s, "data", transport=transport),
-        mesh=mesh, in_specs=(P("data"),), out_specs=P(), check_rep=False)
+        mesh=mesh, in_specs=(P("data"),), out_specs=P(), check_vma=False)
     return jax.jit(fn)(stacks)
 
 

@@ -60,10 +60,7 @@ def test_xla_cost_analysis_is_loop_unaware():
 
     x = jnp.ones((128, 128), jnp.float32)
     compiled = jax.jit(f).lower(x).compile()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict], newer returns dict
-        cost = cost[0]
-    xla_flops = cost["flops"]
+    xla_flops = compiled.cost_analysis()["flops"]
     ours = analyze(compiled.as_text()).flops
     assert xla_flops == pytest.approx(2 * 128 ** 3)          # 1 iteration
     assert ours == pytest.approx(8 * xla_flops)
@@ -73,7 +70,7 @@ def test_collective_wire_model():
     s = CollectiveStat("all-reduce")
     # formulas validated by construction in analyze(); check the ring model
     # numbers on a synthetic record
-    from repro.distributed.hlo_analysis import V5E
+    from repro.distributed.hlo_analysis import PEAKS, V5E_KIND
 
     a = analyze("""
 HloModule m, entry_computation_layout={()->f32[]}
@@ -88,8 +85,9 @@ ENTRY %main.1 () -> f32[] {
     size = 1024 * 1024 * 4
     assert ar.operand_bytes == pytest.approx(size)
     assert ar.wire_bytes == pytest.approx(2 * size * 15 / 16)
-    t = roofline_terms(a)
-    assert t["collective_s"] == pytest.approx(ar.wire_bytes / V5E["ici_gbps"])
+    t = roofline_terms(a, V5E_KIND)
+    assert t["collective_s"] == pytest.approx(
+        ar.wire_bytes / PEAKS[V5E_KIND]["ici_gbps"])
 
 
 def test_roofline_terms_dimensions():
@@ -97,7 +95,17 @@ def test_roofline_terms_dimensions():
         return jnp.sum(a @ b)
 
     a_ = jnp.ones((512, 512), jnp.float32)
-    t = roofline_terms(analyze(_compile(f, a_, a_)))
+    from repro.distributed.hlo_analysis import V5E_KIND
+
+    t = roofline_terms(analyze(_compile(f, a_, a_)), V5E_KIND)
     assert set(t) == {"compute_s", "memory_s", "collective_s"}
     assert t["compute_s"] > 0 and t["memory_s"] > 0
     assert t["collective_s"] == 0.0  # single device: no collectives
+
+
+def test_roofline_terms_refuse_unknown_device():
+    """Peaks are looked up by device kind; a chip without published peaks
+    (or the CPU) is an error, never silently priced as a v5e."""
+    a = analyze(_compile(lambda x: x * 2, jnp.ones((8,), jnp.float32)))
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline_terms(a, "cpu")

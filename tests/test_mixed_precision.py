@@ -336,6 +336,26 @@ def test_footprint_is_dtype_and_epilogue_aware():
     assert 0 < occ <= 1.0
 
 
+def test_footprint_counts_register_tile_padding():
+    """The ENet-512 stem (3x3/s2, 3 channels): its input block
+    (4 phases, 8 rows, 257 cols, 3 ch) sits in VMEM as (264, 128) tiles —
+    4 MiB per buffer, which is what the TPU compiler counts — not the
+    ~100 KB its element count suggests."""
+    geom = dict(x_shape=(1, 512, 512, 3), w_shape=(3, 3, 3, 13), th=8,
+                tc=128, stride=2)
+    x_block = tp.padded_bytes((4, 8, 257, 3), jnp.float32)
+    assert x_block == 4 * 8 * 264 * 128 * 4
+    assert tp.padded_bytes((4, 8, 257, 3), jnp.bfloat16) == \
+        4 * 8 * 272 * 128 * 2
+    fp = tp.footprint_bytes("dense", **geom)
+    assert fp >= 4 * x_block            # cur + next, double-buffered
+    # over the policy budget and the 16 MiB default scoped VMEM, inside
+    # the limit the kernels compile with
+    assert tp.VMEM_BUDGET_BYTES < 16 * 2 ** 20 < fp < tp.VMEM_LIMIT_BYTES
+    small = tp.footprint_bytes("dense", **_POLICY_GEOM, th=8, tc=64)
+    assert small <= tp.VMEM_BUDGET_BYTES
+
+
 def test_rank_marks_over_budget_candidates_inf():
     cands = [(4, 64), (8, 64), (8, 128)]
     ranked = tp.rank("dense", **_POLICY_GEOM, cands=cands, vmem_budget=1)

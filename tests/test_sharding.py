@@ -185,13 +185,14 @@ def test_small_mesh_lower_and_compile():
         import json
         import jax
         from repro.configs import get_reduced
+        from repro.launch.mesh import auto_mesh
         from repro.launch.steps import lower_cell
         import repro.launch.shapes as shapes
         from repro.distributed import hlo_analysis as ha
 
         shapes.SHAPES["t"] = shapes.ShapeCell("t", 64, 8, "train")
         shapes.SHAPES["d"] = shapes.ShapeCell("d", 64, 8, "decode")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = auto_mesh((4, 2), ("data", "model"))
         out = {}
         for cell in ("t", "d"):
             lowered, _ = lower_cell(get_reduced("qwen3-moe-30b-a3b"), cell,
@@ -203,7 +204,8 @@ def test_small_mesh_lower_and_compile():
     """)
     res = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=600,
-                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                              "JAX_PLATFORMS": "cpu"})
     assert res.returncode == 0, res.stderr[-2000:]
     out = json.loads(res.stdout.strip().splitlines()[-1])
     assert out["t"]["flops"] > 0
