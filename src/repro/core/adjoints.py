@@ -27,6 +27,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 
 def flip_io(w: jax.Array) -> jax.Array:
     """Spatially flip an HWIO kernel and swap its in/out channels.
@@ -84,6 +86,7 @@ def _pad_to(x: jax.Array, lo_h: int, hi_h: int, lo_w: int, hi_w: int) -> jax.Arr
 # dense convolution  y = conv(x, w; stride s, pads (pl, ph) per dim)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(obs.GRAD_DX)
 def dense_conv_dx(g: jax.Array, w: jax.Array, stride: int, p_lo: int,
                   h: int, w_in: int, tconv_fn) -> jax.Array:
     """Input-gradient of a strided dense conv == a transposed convolution.
@@ -106,6 +109,7 @@ def dense_conv_dx(g: jax.Array, w: jax.Array, stride: int, p_lo: int,
     return dx[:, :h, :w_in, :]
 
 
+@jax.named_scope(obs.GRAD_DW)
 def dense_conv_dw(x: jax.Array, g: jax.Array, kh: int, kw: int, stride: int,
                   p_lo_h: int, p_lo_w: int) -> jax.Array:
     """Weight-gradient of a dense conv: ``kh*kw`` strided tap gathers of x."""
@@ -131,6 +135,7 @@ def _tconv_grad_pad(g: jax.Array, k: int, p_lo: int, p_hi: int) -> jax.Array:
     return _pad_to(g, k - 1 - p_lo, k - 1 - p_hi, k - 1 - p_lo, k - 1 - p_hi)
 
 
+@jax.named_scope(obs.GRAD_DX)
 def tconv_dx(g: jax.Array, w: jax.Array, stride: int, p_lo: int, p_hi: int,
              conv_fn) -> jax.Array:
     """Input-gradient of a transposed conv == a strided dense convolution.
@@ -146,6 +151,7 @@ def tconv_dx(g: jax.Array, w: jax.Array, stride: int, p_lo: int, p_hi: int,
     return conv_fn(_tconv_grad_pad(g, k, p_lo, p_hi), flip_io(w), stride)
 
 
+@jax.named_scope(obs.GRAD_DW)
 def tconv_dw(x: jax.Array, g: jax.Array, k: int, stride: int, p_lo: int,
              p_hi: int) -> jax.Array:
     """Weight-gradient of a transposed conv: tap gathers of the cotangent.
@@ -163,6 +169,7 @@ def tconv_dw(x: jax.Array, g: jax.Array, k: int, stride: int, p_lo: int,
 # dilated convolution  y = conv(x, w; dilation d, SAME, stride 1)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(obs.GRAD_DX)
 def dilated_conv_dx(g: jax.Array, w: jax.Array, dilation: int,
                     dilated_fn) -> jax.Array:
     """Input-gradient of a SAME dilated conv == the same dilated conv.
@@ -175,6 +182,7 @@ def dilated_conv_dx(g: jax.Array, w: jax.Array, dilation: int,
     return dilated_fn(g, flip_io(w), dilation)
 
 
+@jax.named_scope(obs.GRAD_DW)
 def dilated_conv_dw(x: jax.Array, g: jax.Array, k: int, dilation: int) -> jax.Array:
     """Weight-gradient of a SAME dilated conv: tap gathers at step ``d``.
 

@@ -25,6 +25,11 @@ Two cross-cutting features ride the dispatcher (DESIGN.md §7):
   shape is resolved per layer geometry through
   :mod:`repro.kernels.autotune` (cached sweep; defaults on a cold miss).
 
+Each call runs under one ``jax.named_scope`` of :mod:`repro.obs`
+(``engine.dense``, ``engine.dilated`` or ``engine.transposed``, by geometry
+class), so a profile puts every kernel and layout pass of the call, and of
+its backward, under one engine.
+
 ``conv2d`` is fully differentiable on both backends: the XLA paths are lax
 compositions, and every fused Pallas kernel registers a ``jax.custom_vjp``
 whose backward re-enters the engine through the adjoint symmetry — the
@@ -41,6 +46,7 @@ from __future__ import annotations
 
 import jax
 
+from repro import obs
 from repro.core import dilated as _dil
 from repro.core import transposed as _tr
 from repro.kernels.epilogue import EpilogueSpec, apply_reference, pack_args
@@ -143,70 +149,73 @@ def conv2d(
     eps = pack_args(spec, scale=scale, shift=shift, alpha=alpha,
                     residual=residual)
     ep_kw = dict(zip(spec.slots, eps))
-    kh, kw = w.shape[0], w.shape[1]
-    if transposed:
-        if dilation != 1:
-            raise ValueError("dilated transposed convolution is not supported")
-        if kh != kw:
-            raise ValueError("transposed convolution requires square kernels")
-        p = (kh - 1) // 2 if padding is None else padding
+    engine = (obs.ENGINE_TRANSPOSED if transposed else
+              obs.ENGINE_DILATED if dilation > 1 else obs.ENGINE_DENSE)
+    with jax.named_scope(engine):
+        kh, kw = w.shape[0], w.shape[1]
+        if transposed:
+            if dilation != 1:
+                raise ValueError("dilated transposed convolution is not supported")
+            if kh != kw:
+                raise ValueError("transposed convolution requires square kernels")
+            p = (kh - 1) // 2 if padding is None else padding
+            if backend == "pallas":
+                from repro.kernels.transposed_conv import transposed_conv2d as _ktr
+
+                th, tc = _resolve_tiles("tconv", x, w, stride, 1, th, tc,
+                                        padding=p, output_padding=output_padding,
+                                        epilogue=spec)
+                return _ktr(x, w, stride=stride, padding=p,
+                            output_padding=output_padding, th=th, tc=tc,
+                            interpret=interpret, epilogue=epilogue, **ep_kw)
+            if decomposed:
+                y = _tr.transposed_conv2d_decomposed(
+                    x, w, stride, p, output_padding,
+                    phase_sharding=phase_sharding)
+            else:
+                y = _tr.transposed_conv2d_naive(x, w, stride, p, output_padding)
+            return apply_reference(spec, y, eps)
+        if dilation > 1:
+            if kh != kw:
+                raise ValueError("dilated convolution requires square kernels")
+            if backend == "pallas":
+                if strategy != "batched":
+                    raise ValueError(
+                        f"pallas dilated path is phase-batched only, got {strategy!r}")
+                from repro.kernels.dilated_conv import dilated_conv2d as _kdil
+
+                th, tc = _resolve_tiles("dilated", x, w, stride, dilation, th, tc,
+                                        epilogue=spec)
+                return _kdil(x, w, dilation, stride=stride, th=th, tc=tc,
+                             interpret=interpret, epilogue=epilogue, **ep_kw)
+            if decomposed:
+                y = _dil.dilated_conv2d_decomposed(
+                    x, w, dilation, strategy=strategy, stride=stride,
+                    phase_sharding=phase_sharding)
+            else:
+                y = _dil.dilated_conv2d_naive(x, w, dilation, stride=stride)
+            return apply_reference(spec, y, eps)
+        # plain dense conv (stride >= 1, rectangular kernels welcome)
         if backend == "pallas":
-            from repro.kernels.transposed_conv import transposed_conv2d as _ktr
+            from repro.kernels.conv2d import conv2d as _kconv
 
-            th, tc = _resolve_tiles("tconv", x, w, stride, 1, th, tc,
-                                    padding=p, output_padding=output_padding,
-                                    epilogue=spec)
-            return _ktr(x, w, stride=stride, padding=p,
-                        output_padding=output_padding, th=th, tc=tc,
-                        interpret=interpret, epilogue=epilogue, **ep_kw)
-        if decomposed:
-            y = _tr.transposed_conv2d_decomposed(
-                x, w, stride, p, output_padding,
-                phase_sharding=phase_sharding)
+            th, tc = _resolve_tiles("dense", x, w, stride, 1, th, tc,
+                                    padding=padding, epilogue=spec)
+            return _kconv(x, w, stride=stride,
+                          padding="SAME" if padding is None else padding,
+                          th=th, tc=tc, interpret=interpret, epilogue=epilogue,
+                          **ep_kw)
+        from jax import lax
+
+        if padding is None:     # SAME, asymmetric for even extents
+            pads = [((kh - 1) // 2, kh // 2), ((kw - 1) // 2, kw // 2)]
         else:
-            y = _tr.transposed_conv2d_naive(x, w, stride, p, output_padding)
+            pads = [(padding, padding), (padding, padding)]
+        y = lax.conv_general_dilated(
+            x, w, window_strides=(stride, stride), padding=pads,
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        )
         return apply_reference(spec, y, eps)
-    if dilation > 1:
-        if kh != kw:
-            raise ValueError("dilated convolution requires square kernels")
-        if backend == "pallas":
-            if strategy != "batched":
-                raise ValueError(
-                    f"pallas dilated path is phase-batched only, got {strategy!r}")
-            from repro.kernels.dilated_conv import dilated_conv2d as _kdil
-
-            th, tc = _resolve_tiles("dilated", x, w, stride, dilation, th, tc,
-                                    epilogue=spec)
-            return _kdil(x, w, dilation, stride=stride, th=th, tc=tc,
-                         interpret=interpret, epilogue=epilogue, **ep_kw)
-        if decomposed:
-            y = _dil.dilated_conv2d_decomposed(
-                x, w, dilation, strategy=strategy, stride=stride,
-                phase_sharding=phase_sharding)
-        else:
-            y = _dil.dilated_conv2d_naive(x, w, dilation, stride=stride)
-        return apply_reference(spec, y, eps)
-    # plain dense conv (stride >= 1, rectangular kernels welcome)
-    if backend == "pallas":
-        from repro.kernels.conv2d import conv2d as _kconv
-
-        th, tc = _resolve_tiles("dense", x, w, stride, 1, th, tc,
-                                padding=padding, epilogue=spec)
-        return _kconv(x, w, stride=stride,
-                      padding="SAME" if padding is None else padding,
-                      th=th, tc=tc, interpret=interpret, epilogue=epilogue,
-                      **ep_kw)
-    from jax import lax
-
-    if padding is None:     # SAME, asymmetric for even extents
-        pads = [((kh - 1) // 2, kh // 2), ((kw - 1) // 2, kw // 2)]
-    else:
-        pads = [(padding, padding), (padding, padding)]
-    y = lax.conv_general_dilated(
-        x, w, window_strides=(stride, stride), padding=pads,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )
-    return apply_reference(spec, y, eps)
 
 
 __all__ = ["conv2d"]

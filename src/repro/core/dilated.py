@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
+
 _DIMS = ("NHWC", "HWIO", "NHWC")
 
 
@@ -101,6 +103,7 @@ def dilated_conv2d_naive(x: jax.Array, w: jax.Array, dilation: int,
     )
 
 
+@jax.named_scope(obs.LAYOUT_PHASE_SPLIT)
 def phase_split(x: jax.Array, d: int) -> list[list[jax.Array]]:
     """Split NHWC input into ``d x d`` ragged phase blocks (paper Fig. 4).
 
@@ -109,6 +112,7 @@ def phase_split(x: jax.Array, d: int) -> list[list[jax.Array]]:
     return [[x[:, i::d, j::d, :] for j in range(d)] for i in range(d)]
 
 
+@jax.named_scope(obs.LAYOUT_PHASE_STITCH)
 def phase_stitch(blocks: list[list[jax.Array]], out_shape: tuple[int, ...]) -> jax.Array:
     """Interleave ``d x d`` phase outputs back into a dense NHWC tensor."""
     d = len(blocks)
@@ -119,6 +123,7 @@ def phase_stitch(blocks: list[list[jax.Array]], out_shape: tuple[int, ...]) -> j
     return out
 
 
+@jax.named_scope(obs.LAYOUT_PHASE_SPLIT)
 def _phase_to_batch(x: jax.Array, d: int) -> tuple[jax.Array, int, int]:
     """Pad H, W up to multiples of ``d`` and stack phases on the batch axis.
 
@@ -134,6 +139,7 @@ def _phase_to_batch(x: jax.Array, d: int) -> tuple[jax.Array, int, int]:
     return x.reshape(d * d * n, hp // d, wp // d, c), hp, wp
 
 
+@jax.named_scope(obs.LAYOUT_PHASE_STITCH)
 def _batch_to_phase(y: jax.Array, d: int, n: int, h: int, w_: int) -> jax.Array:
     """Inverse of :func:`_phase_to_batch` (crops the pad-up rows/cols)."""
     _, hb, wb, c = y.shape
@@ -218,25 +224,29 @@ def _dilated_strided_decomposed(x: jax.Array, w: jax.Array, d: int, s: int,
     nx_max = max(e[2] for e in csched)
     rows_span = sb * (ny_max - 1) + k
     cols_span = sb * (nx_max - 1) + k
-    windows = [
-        _class_window(x, d, row, col, rows_span, cols_span)
-        for row in rsched for col in csched
-    ]
+    with jax.named_scope(obs.LAYOUT_PHASE_SPLIT):
+        windows = [
+            _class_window(x, d, row, col, rows_span, cols_span)
+            for row in rsched for col in csched
+        ]
+        if strategy == "batched":
+            xb = jnp.concatenate(windows, axis=0)
     if strategy == "batched":
         # all q*q class windows share one strided dense conv (phase-batched)
-        xb = jnp.concatenate(windows, axis=0)
         if phase_sharding is not None:
             xb = lax.with_sharding_constraint(xb, phase_sharding)
         yb = conv_fn(xb, w, sb)
         planes = [yb[i * n : (i + 1) * n] for i in range(q * q)]
     else:  # ragged: one conv per class (paper-faithful schedule)
         planes = [conv_fn(win, w, sb) for win in windows]
-    out = jnp.zeros((n, oh, ow, cout), x.dtype)
-    i = 0
-    for ji, (_, _, nyi) in enumerate(rsched):
-        for jj, (_, _, nxj) in enumerate(csched):
-            out = out.at[:, ji::q, jj::q, :].set(planes[i][:, :nyi, :nxj, :])
-            i += 1
+    with jax.named_scope(obs.LAYOUT_PHASE_STITCH):
+        out = jnp.zeros((n, oh, ow, cout), x.dtype)
+        i = 0
+        for ji, (_, _, nyi) in enumerate(rsched):
+            for jj, (_, _, nxj) in enumerate(csched):
+                out = out.at[:, ji::q, jj::q, :].set(
+                    planes[i][:, :nyi, :nxj, :])
+                i += 1
     return out
 
 

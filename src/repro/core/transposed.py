@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
+
 _DIMS = ("NHWC", "HWIO", "NHWC")
 
 
@@ -141,23 +143,25 @@ def transposed_conv2d_decomposed(
         pad_top, pad_left = -ro[0], -co[0]
         need_bot = (nyr - 1) + ro[-1] - (h - 1)   # last input row needed minus available
         need_rgt = (nxr - 1) + co[-1] - (w_in - 1)
-        xp = jnp.pad(
-            x,
-            (
-                (0, 0),
-                (max(pad_top, 0), max(need_bot, 0)),
-                (max(pad_left, 0), max(need_rgt, 0)),
-                (0, 0),
-            ),
-        )
-        # crop if offsets start inside the input (pad_top < 0)
-        xp = xp[:, max(-pad_top, 0):, max(-pad_left, 0):, :]
+        with jax.named_scope(obs.LAYOUT_PAD):
+            xp = jnp.pad(
+                x,
+                (
+                    (0, 0),
+                    (max(pad_top, 0), max(need_bot, 0)),
+                    (max(pad_left, 0), max(need_rgt, 0)),
+                    (0, 0),
+                ),
+            )
+            # crop if offsets start inside the input (pad_top < 0)
+            xp = xp[:, max(-pad_top, 0):, max(-pad_left, 0):, :]
         if phase_sharding is not None:
             xp = lax.with_sharding_constraint(xp, phase_sharding)
         plane = lax.conv_general_dilated(
             xp, sub, window_strides=(1, 1), padding="VALID", dimension_numbers=_DIMS,
         )
-        out = out.at[:, ry::s, rx::s, :].set(plane[:, :nyr, :nxr, :])
+        with jax.named_scope(obs.LAYOUT_PARITY_INTERLEAVE):
+            out = out.at[:, ry::s, rx::s, :].set(plane[:, :nyr, :nxr, :])
     return out
 
 

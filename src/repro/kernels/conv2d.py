@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.kernels import tiling_policy
 from repro.kernels.epilogue import EpilogueSpec, apply_tile, pack_args
 from repro.kernels.util import resolve_interpret
@@ -136,7 +137,9 @@ def _chan_operand(v: jax.Array, cout: int, cout_p: int) -> jax.Array:
     """Broadcast a scalar/per-channel operand to a padded (1, cout_p) row."""
     from repro.kernels.epilogue import _chanvec
 
-    return jnp.pad(_chanvec(v, cout), (0, cout_p - cout)).reshape(1, cout_p)
+    with jax.named_scope(obs.LAYOUT_PAD):
+        return jnp.pad(_chanvec(v, cout), (0, cout_p - cout)).reshape(
+            1, cout_p)
 
 
 def _conv2d_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
@@ -164,16 +167,19 @@ def _conv2d_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
     # BlockSpec reads for the halo; phase cols: w_out plus the column halo
     rows_q = h_out_p + th
     cols_q = w_out + (kw - 1) // s
-    xp = jnp.pad(
-        x,
-        ((0, 0), (ph[0], max(s * rows_q - h - ph[0], 0)),
-         (pw[0], max(s * cols_q - w_in - pw[0], 0)), (0, 0)),
-    )[:, :s * rows_q, :s * cols_q, :]
+    with jax.named_scope(obs.LAYOUT_PAD):
+        xp = jnp.pad(
+            x,
+            ((0, 0), (ph[0], max(s * rows_q - h - ph[0], 0)),
+             (pw[0], max(s * cols_q - w_in - pw[0], 0)), (0, 0)),
+        )[:, :s * rows_q, :s * cols_q, :]
+        wp = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, cout_p - cout)))
     # (N, s*Rq, s*Cq, Cin) -> (N, s*s, Rq, Cq, Cin): a layout op (identity
     # for s == 1) that turns every strided tap into a unit-stride window
-    xp = xp.reshape(n, rows_q, s, cols_q, s, cin).transpose(0, 2, 4, 1, 3, 5)
-    xp = xp.reshape(n, s * s, rows_q, cols_q, cin)
-    wp = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, cout_p - cout)))
+    with jax.named_scope(obs.LAYOUT_PHASE_SPLIT):
+        xp = xp.reshape(n, rows_q, s, cols_q, s, cin).transpose(
+            0, 2, 4, 1, 3, 5)
+        xp = xp.reshape(n, s * s, rows_q, cols_q, cin)
 
     # grid order (batch, cout tile, row tile): the row stream is innermost,
     # so the pipeline double-buffers consecutive input row tiles (the halo
@@ -195,8 +201,9 @@ def _conv2d_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
             if v.shape != (n, h_out, w_out, cout):
                 raise ValueError(f"residual shape {v.shape} != output "
                                  f"{(n, h_out, w_out, cout)}")
-            ep_in.append(jnp.pad(v, ((0, 0), (0, h_out_p - h_out), (0, 0),
-                                     (0, cout_p - cout))))
+            with jax.named_scope(obs.LAYOUT_PAD):
+                ep_in.append(jnp.pad(v, ((0, 0), (0, h_out_p - h_out),
+                                         (0, 0), (0, cout_p - cout))))
             ep_specs.append(pl.BlockSpec((1, th, w_out, tc),
                                          lambda b, c, i: (b, i, 0, c)))
         else:
@@ -218,7 +225,8 @@ def _conv2d_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
             vmem_limit_bytes=tiling_policy.VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(xp, xp, wp, *ep_in)
-    return out[:, :h_out, :, :cout]
+    with jax.named_scope(obs.LAYOUT_CROP):
+        return out[:, :h_out, :, :cout]
 
 
 def _conv2d_impl(x: jax.Array, w: jax.Array, stride: int,
@@ -241,6 +249,7 @@ def _conv2d_fwd(x, w, stride, pads, th, tc, interpret):
     return _conv2d_impl(x, w, stride, pads, th, tc, interpret), (x, w)
 
 
+@jax.named_scope(obs.GRAD_DX)
 def _dx_lax(g, w, stride, pads, h, w_in):
     """Fallback input-gradient (rectangular kernels / exotic pads): the same
     adjoint expressed as one lhs-dilated lax convolution."""

@@ -32,6 +32,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels.conv2d import conv2d as _dense_conv
 from repro.kernels.epilogue import EpilogueSpec, apply_reference, pack_args
 from repro.kernels.util import resolve_interpret
@@ -92,6 +93,7 @@ def dilated_conv2d(x: jax.Array, w: jax.Array, dilation: int, *,
     return _dilated_vjp(x, w, d, th, tc, interpret)
 
 
+@jax.named_scope(obs.LAYOUT_PHASE_SPLIT)
 def _phase_to_batch(x: jax.Array, d: int) -> jax.Array:
     """Pad H, W to multiples of ``d`` and stack phases on the batch axis."""
     n, h, w_in, c = x.shape
@@ -121,9 +123,11 @@ def _dilated_impl(x: jax.Array, w: jax.Array, d: int, th: int, tc: int,
                      epilogue=spec if not spec.empty else None, **ep_kw)
 
     # batch -> phases, then interleave and crop the pad-up rows/cols
-    yb = yb.reshape(d, d, n, hp // d, wp // d, cout)
-    y = yb.transpose(2, 3, 0, 4, 1, 5).reshape(n, hp, wp, cout)
-    return y[:, :h, :w_in, :]
+    with jax.named_scope(obs.LAYOUT_PHASE_STITCH):
+        yb = yb.reshape(d, d, n, hp // d, wp // d, cout)
+        y = yb.transpose(2, 3, 0, 4, 1, 5).reshape(n, hp, wp, cout)
+    with jax.named_scope(obs.LAYOUT_CROP):
+        return y[:, :h, :w_in, :]
 
 
 # ---------------------------------------------------------------------------

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.kernels import tiling_policy
 from repro.kernels.epilogue import (EpilogueSpec, apply_reference, apply_tile,
                                     pack_args)
@@ -159,6 +160,7 @@ def transposed_conv2d(x: jax.Array, w: jax.Array, *, stride: int = 2,
                          tc, interpret)
 
 
+@jax.named_scope(obs.LAYOUT_PAD)
 def _residual_to_planes(res: jax.Array, s: int, hb: int, wb: int, rows_p: int,
                         cout_p: int) -> jax.Array:
     """De-interleave an (N, OH, OW, C) residual into padded parity planes.
@@ -202,9 +204,10 @@ def _tconv_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
     rows_p = max((n_row_tiles + 1) * th, h + shift)
     rows_p = math.ceil(rows_p / th) * th
     cols_p = max(wb + halo, w_in + shift)
-    xp = jnp.pad(x, ((0, 0), (shift, rows_p - h - shift),
-                     (shift, cols_p - w_in - shift), (0, 0)))
-    wp = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, cout_p - cout)))
+    with jax.named_scope(obs.LAYOUT_PAD):
+        xp = jnp.pad(x, ((0, 0), (shift, rows_p - h - shift),
+                         (shift, cols_p - w_in - shift), (0, 0)))
+        wp = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, cout_p - cout)))
 
     # grid order (batch, cout tile, row tile): the row stream is innermost —
     # the pipeline double-buffers consecutive input tiles (halo pair advances
@@ -249,11 +252,15 @@ def _tconv_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
         interpret=interpret,
     )(xp, xp, wp, *ep_in)
 
-    planes = planes[:, :, :hb, :, :cout]                   # (N, s*s, Hb, Wb, C)
+    with jax.named_scope(obs.LAYOUT_CROP):
+        planes = planes[:, :, :hb, :, :cout]               # (N, s*s, Hb, Wb, C)
     # interleave parities: out[n, s*b+ry, s*c+rx] = planes[n, s*ry+rx, b, c]
-    planes = planes.reshape(n, s, s, hb, wb, cout)
-    out = planes.transpose(0, 3, 1, 4, 2, 5).reshape(n, hb * s, wb * s, cout)
-    return out[:, :oh, :ow, :]
+    with jax.named_scope(obs.LAYOUT_PARITY_INTERLEAVE):
+        planes = planes.reshape(n, s, s, hb, wb, cout)
+        out = planes.transpose(0, 3, 1, 4, 2, 5).reshape(n, hb * s, wb * s,
+                                                          cout)
+    with jax.named_scope(obs.LAYOUT_CROP):
+        return out[:, :oh, :ow, :]
 
 
 def _tconv_impl(x: jax.Array, w: jax.Array, s: int, p_lo: int,

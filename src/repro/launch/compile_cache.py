@@ -34,7 +34,12 @@ def enable_compile_cache() -> str:
 
     ``$JAX_COMPILATION_CACHE_DIR`` wins when set (JAX already took it from
     the environment); otherwise the cache goes to :data:`DEFAULT_DIR`.
+
+    The key includes the HLO metadata: an executable loaded from the cache
+    keeps the metadata it was compiled with, so without it a profile could
+    show the scope names (``repro.obs``) of another revision of the code.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get(ENV_VAR)
     if env:
         return env

@@ -72,6 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint as ckpt
+from repro import obs
 from repro.core import cycle_model as cm
 from repro.core.gen_spec import GEN_WORKLOADS, UNET_WIDTHS
 from repro.distributed import sharding as shd
@@ -355,7 +356,7 @@ class _DiffusionLane:
             self.slots[i], self._traj[i], self._pos[i] = s, tr, p
             self.active[i] = True
 
-    def tick(self) -> list[GenRequest]:
+    def tick(self, tick: int) -> list[GenRequest]:
         b, k = self.batch, self.scan_steps
         t = np.zeros((b, k), np.int32)
         t_next = np.full((b, k), -1, np.int32)
@@ -370,22 +371,23 @@ class _DiffusionLane:
                 if p + j + 1 < len(traj):
                     t_next[i, j] = traj[p + j + 1]
                 act[i, j] = True
-        if self.batch not in self.compiled_sizes:
-            self.compiled_sizes.add(self.batch)
-        batch = {"t": jnp.asarray(t), "t_next": jnp.asarray(t_next),
-                 "active": jnp.asarray(act)}
-        self.x = self._step(self.params, self.x, batch)
+        self.compiled_sizes.add(self.batch)
+        with obs.span(obs.GEN_DISPATCH, tick=tick):
+            batch = {"t": jnp.asarray(t), "t_next": jnp.asarray(t_next),
+                     "active": jnp.asarray(act)}
+            self.x = self._step(self.params, self.x, batch)
         self.device_steps += 1
         self.substeps += int(act.sum())
         done = []
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            self._pos[i] += int(act[i].sum())
-            if self._pos[i] == len(self._traj[i]):        # landed on x0
-                req.result = np.asarray(self.x[i])
-                done.append(req)
-                self.release(i)
+        with obs.span(obs.GEN_FETCH, tick=tick):
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                self._pos[i] += int(act[i].sum())
+                if self._pos[i] == len(self._traj[i]):    # landed on x0
+                    req.result = np.asarray(self.x[i])
+                    done.append(req)
+                    self.release(i)
         return done
 
 
@@ -491,10 +493,12 @@ class _DCGANLane:
             self.slots[i] = s
             self.active[i] = True
 
-    def tick(self) -> list[GenRequest]:
-        if self.batch not in self.compiled_sizes:
-            self.compiled_sizes.add(self.batch)
-        imgs = np.asarray(self._step(self.params, self.z))
+    def tick(self, tick: int) -> list[GenRequest]:
+        self.compiled_sizes.add(self.batch)
+        with obs.span(obs.GEN_DISPATCH, tick=tick):
+            imgs = self._step(self.params, self.z)
+        with obs.span(obs.GEN_FETCH, tick=tick):
+            imgs = np.asarray(imgs)
         self.device_steps += 1
         done = []
         for i, req in enumerate(self.slots):
@@ -620,9 +624,11 @@ class GenServer:
         self._next_rid = 0
         self._t0: float | None = None
         # per-tick log: (wall_s, dispatches, completions, substeps, cold) —
-        # cold = a lane compiled a new batch shape inside the tick, so warm
-        # throughput can be reported without the compile wall (stats())
+        # cold = JAX built or loaded an executable inside the tick
+        # (obs.compiles() moved), so warm throughput can be reported
+        # without the compile wall (stats())
         self._tick_log: list[tuple[float, int, int, int, bool]] = []
+        self._compiles0 = obs.compiles()
 
     # -------------------------------------------------------------- lanes --
     def _workload_layers(self, workload: str):
@@ -781,8 +787,11 @@ class GenServer:
         aged = (self._tick - req.submit_tick) >= self.starvation_ticks
         return (0 if aged else 1, req.slo.rank, req.deadline_us(), req.rid)
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
+        """Fill free lane slots from the queue; returns how many were
+        admitted."""
         now_us = time.perf_counter() * 1e6
+        admitted = 0
         by_lane: dict[str, list[GenRequest]] = {}
         for req in self._pending:
             by_lane.setdefault(req.workload, []).append(req)
@@ -803,6 +812,8 @@ class GenServer:
                 req.status = "active"
                 lane.admit(req, slot)
                 self._pending.remove(req)
+                admitted += 1
+        return admitted
 
     def _autoscale(self) -> None:
         """Grow a backlogged lane / shrink an underused one, one ladder
@@ -853,7 +864,7 @@ class GenServer:
                     raise RuntimeError(
                         f"injected {lane.backend} dispatch failure on lane "
                         f"{workload!r} at tick {self._tick}")
-                done = lane.tick()
+                done = lane.tick(self._tick)
             except Exception:
                 failed = True
                 attempts += 1
@@ -913,15 +924,18 @@ class GenServer:
         :meth:`_lane_tick`'s retry/degrade ladder).
         """
         t_start = time.perf_counter()
+        compiles0 = obs.compiles()
         if self._t0 is None:
             self._t0 = t_start
         inj = self.faults
         if inj is not None and inj.take(self._tick, kind="kill"):
             raise RuntimeError(f"injected server kill at tick {self._tick}")
-        self._expire()
+        with obs.span(obs.GEN_EXPIRE, tick=self._tick):
+            self._expire()
         if self.autoscale:
             self._autoscale()
-        self._admit()
+        with obs.span(obs.GEN_ADMIT, tick=self._tick) as sp:
+            sp.set_metadata(admitted=self._admit())
         if inj is not None:
             stall = inj.sleep_faults(self._tick)
             if stall > 0:
@@ -934,10 +948,8 @@ class GenServer:
                     lane.corrupt(f.slot)
         done: list[GenRequest] = []
         dispatches = substeps = 0
-        cold = False
         for workload, lane in self._lanes.items():
             if lane.busy:
-                cold = cold or lane.batch not in lane.compiled_sizes
                 sub0 = lane.substeps
                 done.extend(self._lane_tick(workload, lane))
                 dispatches += 1
@@ -950,8 +962,8 @@ class GenServer:
             req.done_wall = t_end
             req.status = "done"
             self._done[req.rid] = req
-        self._tick_log.append(
-            (t_end - t_start, dispatches, len(done), substeps, cold))
+        self._tick_log.append((t_end - t_start, dispatches, len(done),
+                               substeps, obs.compiles() != compiles0))
         if self.watchdog is not None and dispatches:
             self._stuck = (self._stuck + 1 if self.watchdog.observe(
                 self._tick - 1, t_end - t_start) else 0)
@@ -1194,10 +1206,10 @@ class GenServer:
         waits = [r.wait_ticks for r in self._done.values()]
         lats = sorted(r.latency_s for r in self._done.values())
         statuses = [r.status for r in self._requests.values()]
-        # warm-steady window: ticks in which no lane compiled a new batch
-        # shape — first-tick (and resize-tick) jit compiles are excluded the
-        # same way ``kernels.util.time_call`` excludes compile from every
-        # other timed region in the repo
+        # warm-steady window: ticks in which JAX built no executable —
+        # first-tick (and resize-tick) compiles are excluded the same way
+        # ``kernels.util.time_call`` excludes compile from every other
+        # timed region in the repo
         warm = [t for t in self._tick_log if not t[4]]
         warm_wall = sum(t[0] for t in warm)
         warm_imgs = sum(t[2] for t in warm)
@@ -1230,6 +1242,8 @@ class GenServer:
             "recoveries": float(self._recoveries),
             "corrupt": float(statuses.count("corrupt")),
             "snapshots": float(self._snapshots),
+            # executables JAX built or loaded since the server was made
+            "compiles": float(obs.compiles() - self._compiles0),
         }
 
 

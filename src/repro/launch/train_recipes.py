@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.distributed import compression as _compression
 from repro.distributed import sharding as _sharding
 from repro.models import dcgan, enet, espnet
@@ -61,16 +62,18 @@ class TrainState(NamedTuple):
 def _seg_loss(forward, params, batch, **fw_kw):
     """Mean per-pixel NLL, reduced in fp32 regardless of compute dtype."""
     logits = forward(params, batch["image"], **fw_kw)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, batch["label"][..., None], axis=-1)
-    return jnp.mean(nll)
+    with jax.named_scope(obs.TRAIN_LOSS):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, batch["label"][..., None], axis=-1)
+        return jnp.mean(nll)
 
 
 def _gen_loss(params, batch, **fw_kw):
     """Generator pixel-regression smoke objective (fp32 reduction)."""
     img = dcgan.forward(params, batch["z"], **fw_kw)
-    err = img.astype(jnp.float32) - batch["target"].astype(jnp.float32)
-    return jnp.mean(jnp.square(err))
+    with jax.named_scope(obs.TRAIN_LOSS):
+        err = img.astype(jnp.float32) - batch["target"].astype(jnp.float32)
+        return jnp.mean(jnp.square(err))
 
 
 def _loss_fn(model: str, *, backend: str, decomposed: bool,
@@ -127,19 +130,20 @@ def make_train_step(model: str, *, backend: str = "xla",
 
         (_, loss), grads = jax.value_and_grad(scaled_loss,
                                               has_aux=True)(state.params)
-        grads = scaler.unscale(state.scale, grads)
-        finite = scaler.all_finite(grads)
-        # a non-finite gradient must not reach the AdamW moments: zero the
-        # grads before the update, then discard the whole update anyway
-        zeros = jax.tree_util.tree_map(jnp.zeros_like, grads)
-        safe = select_tree(finite, grads, zeros)
-        step_lr = lr(state.opt.step) if callable(lr) else jnp.float32(lr)
-        new_params, new_opt, gnorm = adamw_update(
-            safe, state.opt, state.params, lr=step_lr,
-            weight_decay=weight_decay)
-        new_params = select_tree(finite, new_params, state.params)
-        new_opt = select_tree(finite, new_opt, state.opt)
-        scale_state = scaler.update(state.scale, finite)
+        with jax.named_scope(obs.TRAIN_OPTIMIZER):
+            grads = scaler.unscale(state.scale, grads)
+            finite = scaler.all_finite(grads)
+            # a non-finite gradient must not reach the AdamW moments: zero
+            # the grads before the update, then discard the update anyway
+            zeros = jax.tree_util.tree_map(jnp.zeros_like, grads)
+            safe = select_tree(finite, grads, zeros)
+            step_lr = lr(state.opt.step) if callable(lr) else jnp.float32(lr)
+            new_params, new_opt, gnorm = adamw_update(
+                safe, state.opt, state.params, lr=step_lr,
+                weight_decay=weight_decay)
+            new_params = select_tree(finite, new_params, state.params)
+            new_opt = select_tree(finite, new_opt, state.opt)
+            scale_state = scaler.update(state.scale, finite)
         metrics = {"loss": loss,
                    "grad_norm": jnp.where(finite, gnorm, 0.0),
                    "scale": scale_state.scale,
@@ -252,18 +256,19 @@ def make_sharded_train_step(model: str, mesh, *, virtual_shards: int = 8,
         grad_sum, losses = chunk_grads(state.params, state.scale, chunks)
         # equal-size chunks: the batch mean is the mean of chunk means
         loss = jnp.sum(losses.astype(jnp.float32)) / virtual_shards
-        grads = scaler.unscale(state.scale, grad_sum)
-        grads = jax.tree_util.tree_map(
-            lambda g: g / virtual_shards, grads)
-        finite = scaler.all_finite(grads)
-        zeros = jax.tree_util.tree_map(jnp.zeros_like, grads)
-        safe = select_tree(finite, grads, zeros)
-        new_params, new_opt, gnorm = adamw_update(
-            safe, state.opt, state.params, lr=jnp.float32(lr),
-            weight_decay=weight_decay)
-        new_params = select_tree(finite, new_params, state.params)
-        new_opt = select_tree(finite, new_opt, state.opt)
-        scale_state = scaler.update(state.scale, finite)
+        with jax.named_scope(obs.TRAIN_OPTIMIZER):
+            grads = scaler.unscale(state.scale, grad_sum)
+            grads = jax.tree_util.tree_map(
+                lambda g: g / virtual_shards, grads)
+            finite = scaler.all_finite(grads)
+            zeros = jax.tree_util.tree_map(jnp.zeros_like, grads)
+            safe = select_tree(finite, grads, zeros)
+            new_params, new_opt, gnorm = adamw_update(
+                safe, state.opt, state.params, lr=jnp.float32(lr),
+                weight_decay=weight_decay)
+            new_params = select_tree(finite, new_params, state.params)
+            new_opt = select_tree(finite, new_opt, state.opt)
+            scale_state = scaler.update(state.scale, finite)
         metrics = {"loss": loss,
                    "grad_norm": jnp.where(finite, gnorm, 0.0),
                    "scale": scale_state.scale,
