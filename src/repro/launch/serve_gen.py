@@ -91,6 +91,54 @@ def init_noise(seed: int, shape: tuple[int, ...]) -> jax.Array:
     return jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
 
 
+def _refill(state: jax.Array, seeds: jax.Array,
+            fresh: jax.Array) -> jax.Array:
+    """Slot ``i`` of ``state`` becomes ``init_noise(seeds[i])`` (cast to
+    the state's dtype) where ``fresh[i]``; every other slot passes through
+    bitwise."""
+    noise = jax.vmap(lambda s: init_noise(s, state.shape[1:]))(seeds)
+    keep = fresh.reshape(fresh.shape + (1,) * (state.ndim - 1))
+    return jnp.where(keep, noise.astype(state.dtype), state)
+
+
+class _Refill:
+    """A lane's admissions of one tick, written to its slot state in one
+    device call.
+
+    ``put`` records a slot's seed on the host; ``__call__`` then runs the
+    jitted :func:`_refill` over every slot at once (fixed shape: one
+    executable per lane batch size, full and partial ticks alike), with
+    the state donated and, on a meshed lane, its sharding kept.
+    ``init_noise`` keys on ``PRNGKey(seed)``, which (64-bit mode off)
+    takes the seed modulo 2**32, so a ``uint32`` slot holds any int seed.
+    """
+
+    def __init__(self):
+        # a function of the lane's own: its jit cache holds this lane's
+        # executables alone, one per batch size, kept across resizes
+        self._fun = functools.partial(_refill)
+
+    def alloc(self, batch: int, sharding=None) -> None:
+        kw = {} if sharding is None else {"out_shardings": sharding}
+        self.fn = jax.jit(self._fun, donate_argnums=(0,), **kw)
+        self.seeds = np.zeros(batch, np.uint32)
+        self.fresh = np.zeros(batch, bool)
+
+    def put(self, slot: int, seed: int) -> None:
+        self.seeds[slot] = seed & 0xFFFFFFFF
+        self.fresh[slot] = True
+
+    def __call__(self, state: jax.Array) -> jax.Array | None:
+        """The refilled state, or None when no slot was put since the last
+        call.  The host arrays go in as copies: the call is asynchronous,
+        and on the CPU a device array may share an argument's memory."""
+        if not self.fresh.any():
+            return None
+        out = self.fn(state, self.seeds.copy(), self.fresh.copy())
+        self.fresh[:] = False
+        return out
+
+
 # ---------------------------------------------------------------------------
 # SLO classes
 # ---------------------------------------------------------------------------
@@ -248,6 +296,7 @@ class _DiffusionLane:
         self.device_steps = 0       # host dispatches (one per busy tick)
         self.substeps = 0           # active trajectory steps actually taken
         self.compiled_sizes: set[int] = set()
+        self._refill = _Refill()
         self._alloc(batch)
 
     def set_backend(self, backend: str) -> None:
@@ -296,6 +345,7 @@ class _DiffusionLane:
         self._step, sh = self._jit_step(batch)
         x = jnp.zeros((batch,) + self.image_shape, self._x_dtype)
         self.x = x if sh is None else jax.device_put(x, sh)
+        self._refill.alloc(batch, sh)
         self.slots: list[GenRequest | None] = [None] * batch
         self._traj: list[np.ndarray | None] = [None] * batch
         self._pos = [0] * batch
@@ -321,8 +371,16 @@ class _DiffusionLane:
         self._traj[slot] = traj
         self._pos[slot] = 0
         self.active[slot] = True
-        self.x = self.x.at[slot].set(
-            init_noise(req.seed, self.image_shape).astype(self.x.dtype))
+        self._refill.put(slot, req.seed)
+
+    def refill(self) -> bool:
+        """Write x_T of every slot admitted since the last refill, in one
+        device call; returns whether a call was made."""
+        x = self._refill(self.x)
+        if x is None:
+            return False
+        self.x = x
+        return True
 
     def release(self, slot: int) -> None:
         """Vacate a slot mid-flight (cancel/timeout): the slot is reusable
@@ -420,6 +478,7 @@ class _DCGANLane:
         self.device_steps = 0
         self.substeps = 0
         self.compiled_sizes: set[int] = set()
+        self._refill = _Refill()
         self._alloc(batch)
 
     def set_backend(self, backend: str) -> None:
@@ -451,6 +510,8 @@ class _DCGANLane:
     def _alloc(self, batch: int) -> None:
         self.batch = batch
         self.z = self._place(jnp.zeros((batch, self.nz), jnp.float32))
+        self._refill.alloc(
+            batch, None if self.mesh is None else self.z.sharding)
         self.slots: list[GenRequest | None] = [None] * batch
         self.active = np.zeros(batch, bool)
 
@@ -471,7 +532,16 @@ class _DCGANLane:
     def admit(self, req: GenRequest, slot: int) -> None:
         self.slots[slot] = req
         self.active[slot] = True
-        self.z = self.z.at[slot].set(init_noise(req.seed, (self.nz,)))
+        self._refill.put(slot, req.seed)
+
+    def refill(self) -> bool:
+        """Write the latent of every slot admitted since the last refill,
+        in one device call; returns whether a call was made."""
+        z = self._refill(self.z)
+        if z is None:
+            return False
+        self.z = z
+        return True
 
     def release(self, slot: int) -> None:
         self.slots[slot] = None
@@ -534,7 +604,9 @@ class GenServer:
     admission attempt whose remaining budget is below the estimate *sheds*
     the request (status ``"shed"``) instead of burning a slot on a
     guaranteed SLO miss — the scheduler finally acting on the PR-6
-    admission estimates.
+    admission estimates.  Admitting a request is host bookkeeping; each
+    lane then writes the noise of every slot it admitted in one device
+    call a tick (``stats()["admit_calls"]`` counts them).
 
     ``scan_steps`` fuses K DDIM steps per dispatch (``"auto"`` sizes K per
     lane from the calibration via :func:`choose_scan_steps`); ``autoscale``
@@ -615,6 +687,7 @@ class GenServer:
         self._recoveries = 0
         self._snapshots = 0
         self._stuck = 0                       # consecutive stuck-tick flags
+        self._admit_calls = 0                 # lane refill calls (_admit)
         self._lanes: dict[str, _DiffusionLane | _DCGANLane] = {}
         self._idle_ticks: dict[str, int] = {}
         self._pending: list[GenRequest] = []
@@ -771,14 +844,16 @@ class GenServer:
         return True
 
     def _expire(self) -> None:
-        """Time out requests (queued or in-flight) past their tick budget."""
-        for req in list(self._requests.values()):
-            if req.status not in ("pending", "active"):
-                continue
-            if req.timeout_ticks is None:
-                continue
-            if self._tick - req.submit_tick >= req.timeout_ticks:
-                self.cancel(req.rid, status="timeout")
+        """Time out requests (queued or in-flight) past their tick budget,
+        in rid order.  Only live requests are visited — the queue and the
+        occupied slots — so a tick's cost does not grow with the requests
+        served before it."""
+        live = [*self._pending, *(r for lane in self._lanes.values()
+                                  for r in lane.slots if r is not None)]
+        due = [r for r in live if r.timeout_ticks is not None
+               and self._tick - r.submit_tick >= r.timeout_ticks]
+        for req in sorted(due, key=lambda r: r.rid):
+            self.cancel(req.rid, status="timeout")
 
     def _admission_key(self, req: GenRequest):
         """Priority ordering: aged requests first (cross-class starvation
@@ -787,11 +862,12 @@ class GenServer:
         aged = (self._tick - req.submit_tick) >= self.starvation_ticks
         return (0 if aged else 1, req.slo.rank, req.deadline_us(), req.rid)
 
-    def _admit(self) -> int:
-        """Fill free lane slots from the queue; returns how many were
-        admitted."""
+    def _admit(self) -> tuple[int, int]:
+        """Fill free lane slots from the queue, then write the admitted
+        slots' noise with one refill call per lane that admitted any;
+        returns ``(admitted, refill calls)``."""
         now_us = time.perf_counter() * 1e6
-        admitted = 0
+        admitted = refills = 0
         by_lane: dict[str, list[GenRequest]] = {}
         for req in self._pending:
             by_lane.setdefault(req.workload, []).append(req)
@@ -813,7 +889,8 @@ class GenServer:
                 lane.admit(req, slot)
                 self._pending.remove(req)
                 admitted += 1
-        return admitted
+            refills += lane.refill()
+        return admitted, refills
 
     def _autoscale(self) -> None:
         """Grow a backlogged lane / shrink an underused one, one ladder
@@ -935,7 +1012,9 @@ class GenServer:
         if self.autoscale:
             self._autoscale()
         with obs.span(obs.GEN_ADMIT, tick=self._tick) as sp:
-            sp.set_metadata(admitted=self._admit())
+            admitted, refills = self._admit()
+            sp.set_metadata(admitted=admitted, refills=refills)
+        self._admit_calls += refills
         if inj is not None:
             stall = inj.sleep_faults(self._tick)
             if stall > 0:
@@ -1242,6 +1321,9 @@ class GenServer:
             "recoveries": float(self._recoveries),
             "corrupt": float(statuses.count("corrupt")),
             "snapshots": float(self._snapshots),
+            # lane refill calls since the server was made: requests
+            # admitted / admit_calls is how far admission batches
+            "admit_calls": float(self._admit_calls),
             # executables JAX built or loaded since the server was made
             "compiles": float(obs.compiles() - self._compiles0),
         }

@@ -65,6 +65,7 @@ def test_gen_server_tick_leaves_its_spans_in_order(tmp_path):
     srv.run()                                   # compiles outside the trace
     for i in range(2):
         srv.submit("dcgan64", seed=10 + i)
+    calls0 = srv.stats()["admit_calls"]
     jax.profiler.start_trace(str(tmp_path))
     try:
         srv.step()
@@ -79,6 +80,7 @@ def test_gen_server_tick_leaves_its_spans_in_order(tmp_path):
         obs.GEN_EXPIRE, obs.GEN_ADMIT, obs.GEN_DISPATCH, obs.GEN_FETCH]
     assert all(st["tick"] == 1 for _, _, _, st in events)
     assert events[1][3]["admitted"] == 2
+    assert events[1][3]["refills"] == 1 == srv.stats()["admit_calls"] - calls0
     assert all(e0 <= s1 for (_, e0, _, _), (s1, _, _, _)
                in zip(events, events[1:]))
 

@@ -544,3 +544,206 @@ def test_stats_reports_warm_throughput(denoiser):
     assert st["warm_images_per_s"] > st["images_per_s"]
     assert st["warm_steps_per_s"] > 0
     assert st["latency_p99_s"] >= st["latency_p50_s"] > 0
+
+
+# ------------------------------- batched admission, live-only expiry ---
+
+_EDGE_SEEDS = (0, 1, 2**31 - 1, 2**31, 2**32 - 1)
+
+
+def _dcgan_server(batch, nz=16, **kw):
+    params = dcgan.init_params(jax.random.PRNGKey(1), size=64, nz=nz, ngf=4)
+    return GenServer(batch=batch, dcgan_nz=nz, params={"dcgan64": params},
+                     **kw), params
+
+
+@pytest.mark.parametrize("lane,compute_dtype", [
+    ("dcgan64", None), ("unet_dec", None), ("unet_dec", "bfloat16")])
+def test_refill_is_bitwise_init_noise(denoiser, lane, compute_dtype):
+    """One refill call writes every admitted slot's noise exactly as
+    ``init_noise`` draws it for the request's seed (cast to the lane's
+    state dtype), seeds at the edges of 32 bits included."""
+    if lane == "dcgan64":
+        srv, _ = _dcgan_server(len(_EDGE_SEEDS))
+    else:
+        srv = _server(denoiser, batch=len(_EDGE_SEEDS),
+                      compute_dtype=compute_dtype)
+    for s in _EDGE_SEEDS:
+        srv.submit(lane, steps=2, seed=s)
+    assert srv._admit() == (len(_EDGE_SEEDS), 1)
+    ln = srv._lanes[lane]
+    state = np.asarray(ln.z if lane == "dcgan64" else ln.x)
+    shape = (16,) if lane == "dcgan64" else (_SIZE, _SIZE, 3)
+    for i, req in enumerate(ln.slots):
+        want = init_noise(req.seed, shape).astype(state.dtype)
+        np.testing.assert_array_equal(state[i], np.asarray(want))
+    assert state.dtype == (jnp.bfloat16 if compute_dtype else np.float32)
+
+
+def test_served_dcgan_image_is_generator_of_its_seed():
+    """A served image is the lane's generator applied to ``init_noise`` of
+    its seed, bitwise, on a full tick and on a partial one."""
+    srv, params = _dcgan_server(3)
+    fwd = jax.jit(lambda p, z: dcgan.forward(p, z, decomposed=True,
+                                             backend="xla"))
+    slots = [0, 0, 0]                  # a partial tick leaves a stale slot
+    for seeds in ((2**31, 5, 2**32 - 1), (17, 2**31 - 1)):
+        rids = [srv.submit("dcgan64", seed=s) for s in seeds]
+        images = srv.run()
+        slots[:len(seeds)] = seeds
+        z = jnp.stack([init_noise(s, (16,)) for s in slots])
+        want = np.asarray(fwd(params, z))
+        for i, r in enumerate(rids):
+            np.testing.assert_array_equal(images[r], want[i])
+
+
+def test_one_refill_per_admitting_tick():
+    """A lane writes its admissions with exactly one device call on a tick
+    that admits anything — full or partial, one executable for both — and
+    none on a tick that admits nothing."""
+    srv, _ = _dcgan_server(4)
+    lane = srv._lane("dcgan64")
+    fn, calls = lane._refill.fn, []
+    lane._refill.fn = lambda *a: calls.append(len(calls)) or fn(*a)
+    for n in (4, 2, 0, 3):                         # full, partial, none
+        for i in range(n):
+            srv.submit("dcgan64", seed=100 * n + i)
+        before = (len(calls), srv.stats()["admit_calls"])
+        srv.step()
+        assert len(calls) - before[0] == (1 if n else 0)
+        assert srv.stats()["admit_calls"] - before[1] == (1 if n else 0)
+    assert srv.stats()["admit_calls"] == 3
+    assert fn._cache_size() == 1
+    # the partial tick compiled nothing: the refill's shape is fixed
+    assert not any(cold for *_, cold in srv._tick_log[1:])
+
+
+def _two_lane_server(denoiser, **kw):
+    return GenServer(unet_widths=_WIDTHS, unet_hw=_HW, dcgan_nz=16,
+                     params={"unet_dec": denoiser,
+                             "dcgan64": dcgan.init_params(
+                                 jax.random.PRNGKey(1), size=64, nz=16,
+                                 ngf=4)}, **kw)
+
+
+def test_refill_per_lane_and_per_batch_size(denoiser):
+    """Two lanes admitting in one tick make one call each; a lane that
+    autoscales compiles one refill executable per batch size it admits
+    at, and keeps it across resizes."""
+    srv = _two_lane_server(denoiser, batch=1, autoscale=True, max_batch=4,
+                           shrink_patience=1)
+    refilled = {"unet_dec": [], "dcgan64": []}
+    for w in refilled:
+        lane = srv._lane(w)
+
+        def spy(lane=lane, real=lane.refill, log=refilled[w]):
+            made = real()
+            if made:
+                log.append(lane.batch)
+            return made
+        lane.refill = spy
+    for wave in range(2):                          # grow, shrink, grow
+        for i in range(4):
+            srv.submit("unet_dec", steps=2, seed=i)
+            srv.submit("dcgan64", seed=i)
+        srv.step()
+        if wave == 0:                              # both lanes grew to 2
+            assert srv.stats()["admit_calls"] == 2
+        srv.run()
+        for _ in range(3):                         # idle: lanes shrink
+            srv.step()
+        assert all(lane.batch == 1 for lane in srv._lanes.values())
+    assert len(set(refilled["unet_dec"])) > 1
+    assert srv.stats()["admit_calls"] == sum(map(len, refilled.values()))
+    for w, lane in srv._lanes.items():
+        assert lane._refill.fn._cache_size() == len(set(refilled[w]))
+
+
+def _hog_then_expire(denoiser, history: int):
+    """Serve ``history`` DCGAN requests, then submit a diffusion request
+    with a 3-tick budget behind a slot hog; returns the request's submit
+    tick and the tick whose expiry pass timed it out."""
+    srv = _two_lane_server(denoiser, batch=1, scan_steps=1)
+    for i in range(history):
+        srv.submit("dcgan64", seed=i)
+    srv.run()
+    srv.submit("unet_dec", steps=50, seed=0)       # hog, no budget
+    rid = srv.submit("unet_dec", steps=1, seed=1, timeout_ticks=3)
+    t0 = srv._tick
+    while srv.request(rid).status == "pending":
+        tick = srv._tick
+        srv.step()
+    return srv, rid, t0, tick
+
+
+def test_expiry_tick_unchanged_after_long_history(denoiser):
+    """A queued request times out on the same tick of its life whether
+    the server has served nothing or many requests before it."""
+    fresh, rid_a, t_a, tick_a = _hog_then_expire(denoiser, 0)
+    old, rid_b, t_b, tick_b = _hog_then_expire(denoiser, 40)
+    assert fresh.request(rid_a).status == old.request(rid_b).status == \
+        "timeout"
+    assert tick_a - t_a == tick_b - t_b == 3
+    assert len(old._done) == 40
+
+
+def test_expiry_cancels_in_rid_order(denoiser):
+    """Requests expiring on one tick, queued and in flight, time out in
+    rid order, though the pass gathers the queue before the slots."""
+    srv = _server(denoiser, batch=2, scan_steps=1)
+    rids = [srv.submit("unet_dec", steps=s, seed=i, timeout_ticks=3)
+            for i, s in enumerate((20, 20, 1, 1))]
+    order, cancel = [], srv.cancel
+    srv.cancel = lambda rid, status="cancelled": (
+        order.append(rid), cancel(rid, status))[1]
+    srv.step()                                     # 0, 1 in flight
+    assert srv.request(rids[2]).status == "pending"
+    srv.run()
+    assert order == rids
+    assert all(srv.request(r).status == "timeout" for r in rids)
+
+
+class _CountingDict(dict):
+    """A request table that counts every walk over it."""
+
+    def __init__(self, *args):
+        super().__init__(*args)
+        self.walks = 0
+
+    def _walk(self, it):
+        self.walks += 1
+        return it
+
+    def values(self):
+        return self._walk(super().values())
+
+    def items(self):
+        return self._walk(super().items())
+
+    def keys(self):
+        return self._walk(super().keys())
+
+    def __iter__(self):
+        return self._walk(super().__iter__())
+
+
+def test_expire_visits_only_live_requests(denoiser):
+    """The expiry pass never walks the table of every request ever
+    submitted: with 30 done requests behind them, a queued and an
+    in-flight request time out on their ticks and the table is never
+    walked."""
+    srv = _two_lane_server(denoiser, batch=1, scan_steps=1)
+    for i in range(30):
+        srv.submit("dcgan64", seed=i)
+    srv.run()
+    srv._requests = table = _CountingDict(srv._requests)
+    inflight = srv.submit("unet_dec", steps=50, seed=0, timeout_ticks=2)
+    queued = srv.submit("unet_dec", steps=1, seed=1, timeout_ticks=1)
+    srv.step()                                     # inflight admitted
+    srv.step()                                     # queued: 1 tick old
+    assert srv.request(queued).status == "timeout"
+    assert srv.request(inflight).status == "active"
+    srv.step()                                     # inflight: 2 ticks old
+    assert srv.request(inflight).status == "timeout"
+    assert srv._lanes["unet_dec"].slots == [None]
+    assert table.walks == 0 and len(table) == 32
