@@ -78,14 +78,11 @@ def _gen_loss(params, batch, **fw_kw):
 
 def _loss_fn(model: str, *, backend: str, decomposed: bool,
              interpret: bool | None, compute_dtype: str | None):
-    if model == "enet":
+    if model in ("enet", "espnet"):
+        forward = enet.forward if model == "enet" else espnet.forward
         kw = dict(backend=backend, decomposed=decomposed,
                   interpret=interpret, compute_dtype=compute_dtype)
-        return functools.partial(_seg_loss, enet.forward, **kw)
-    if model == "espnet":
-        kw = dict(backend=backend, decomposed=decomposed,
-                  compute_dtype=compute_dtype)
-        return functools.partial(_seg_loss, espnet.forward, **kw)
+        return functools.partial(_seg_loss, forward, **kw)
     if model == "dcgan":
         kw = dict(backend=backend, decomposed=decomposed,
                   interpret=interpret, compute_dtype=compute_dtype)

@@ -10,7 +10,9 @@ Two kinds of name, both read from a ``jax.profiler`` trace:
   falls under one ``engine.*`` scope (set by ``core.decompose.conv2d``);
   the decomposition's layout passes under ``layout.*``; the custom-VJP
   backward rules under ``grad.dx`` / ``grad.dw``; the train step's loss
-  and optimizer under ``train.*``.
+  and optimizer under ``train.*``; ESPNet's elementwise work between its
+  convolutions under ``esp.*`` (each ESP module's merge, and the input
+  reinforcement).
 * **host spans** (:func:`span`, a ``jax.profiler.TraceAnnotation``): they
   write into the profiler's own trace, on the clock of the device planes,
   and cost a check of a flag when no profiler runs.  ``GenServer.step``
@@ -38,6 +40,9 @@ LAYOUT_PHASE_STITCH = "layout.phase_stitch"
 LAYOUT_PARITY_INTERLEAVE = "layout.parity_interleave"
 LAYOUT_PAD = "layout.pad"
 LAYOUT_CROP = "layout.crop"
+
+ESP_MERGE = "esp.merge"
+ESP_REINFORCE = "esp.reinforce"
 
 GRAD_DX = "grad.dx"
 GRAD_DW = "grad.dw"

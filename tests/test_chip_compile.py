@@ -1,11 +1,12 @@
 """Compile the main path's Pallas kernels for a described TPU v5e chip.
 
-Nothing runs: each case lowers an ENet-512 layer through the engine entry
-point (``repro.core.decompose.conv2d``, ``backend="pallas"``,
-``interpret=False``) with shapes placed on one device of a described
-``v5e:2x2`` topology, and compiles it with the TPU compiler that ships with
-JAX.  That is where Mosaic refuses what interpret mode accepts: strided
-value slices, unaligned reshapes, blocks that overflow scoped VMEM.
+Nothing runs: each case lowers an ENet-512 or ESPNet-1024x512 layer
+through the engine entry point (``repro.core.decompose.conv2d``,
+``backend="pallas"``, ``interpret=False``) with shapes placed on one
+device of a described ``v5e:2x2`` topology, and compiles it with the TPU
+compiler that ships with JAX.  That is where Mosaic refuses what interpret
+mode accepts: strided value slices, unaligned reshapes, blocks that
+overflow scoped VMEM.
 
 The topology is described inside a module fixture, never at import: only
 one process may hold the TPU library, and every test worker imports every
@@ -37,6 +38,20 @@ _CASES = [
     ("b2.3-asym-1x5", (1, 64, 64, 32), (1, 5, 32, 32), {}, _BN_ACT),
     ("fullconv-tconv-16to19", (1, 256, 256, 16), (3, 3, 16, 19),
      dict(stride=2, transposed=True, output_padding=1), None),
+    # ESPNet at 1024x512 (20 classes): odd widths, non-square maps, the
+    # d=16 branches' 4x8 and 8x16 phase planes, the k = s = 2 upsamplers
+    ("espnet-l2.0-reduce-3x3s2-19to12", (1, 256, 512, 19), (3, 3, 19, 12),
+     dict(stride=2), None),
+    ("espnet-l2-d16-12ch", (1, 128, 256, 12), (3, 3, 12, 12),
+     dict(dilation=16), None),
+    ("espnet-l3-d16-25ch", (1, 64, 128, 25), (3, 3, 25, 25),
+     dict(dilation=16), None),
+    ("espnet-l3.0-reduce-3x3s2-131to25", (1, 128, 256, 131),
+     (3, 3, 131, 25), dict(stride=2), None),
+    ("espnet-up1-tconv-2x2s2", (1, 256, 512, 20), (2, 2, 20, 20),
+     dict(stride=2, transposed=True, padding=1, output_padding=0), None),
+    ("espnet-fuse-3x3-39to20", (1, 256, 512, 39), (3, 3, 39, 20), {},
+     _BN_ACT),
 ]
 
 

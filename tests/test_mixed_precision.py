@@ -156,9 +156,9 @@ def test_model_forwards_return_bf16():
     assert out.dtype == jnp.bfloat16 and out.shape[-1] == 4
     assert p["initial"].dtype == jnp.float32
 
-    p = espnet.init_params(key, num_classes=4)
+    p = espnet.init_params(key, num_classes=5)    # the decoder ESP needs 5
     out = espnet.forward(p, img, compute_dtype="bf16")
-    assert out.dtype == jnp.bfloat16 and out.shape[-1] == 4
+    assert out.dtype == jnp.bfloat16 and out.shape[-1] == 5
 
     p = dcgan.init_params(key, size=64, nz=8, ngf=8)
     out = dcgan.forward(p, jax.random.normal(key, (2, 8)),
@@ -256,7 +256,7 @@ def test_select_tree_is_bitwise():
 
 # -------------------------------------------------------------- recipes ----
 
-def _seg_batch(key, classes=4, hw=16):
+def _seg_batch(key, classes=5, hw=16):
     k1, k2 = jax.random.split(key)
     return {"image": jax.random.normal(k1, (1, hw, hw, 3), jnp.float32),
             "label": jax.random.randint(k2, (1, hw, hw), 0, classes)}
@@ -266,7 +266,7 @@ def test_recipe_bf16_step_matches_fp32():
     """One ESPNet step in bf16 lands near the fp32 step: same loss (5%) and
     gradient norm (10%), no skip, untouched scale."""
     key = jax.random.PRNGKey(0)
-    params = espnet.init_params(key, num_classes=4)
+    params = espnet.init_params(key, num_classes=5)
     batch = _seg_batch(jax.random.PRNGKey(1))
     losses, gnorms = {}, {}
     for cd in (None, "bf16"):
@@ -278,7 +278,7 @@ def test_recipe_bf16_step_matches_fp32():
         losses[cd], gnorms[cd] = (float(metrics["loss"]),
                                   float(metrics["grad_norm"]))
         # masters stay fp32 through the update
-        assert state.params["stem"].dtype == jnp.float32
+        assert state.params["level1"].dtype == jnp.float32
     assert abs(losses["bf16"] / losses[None] - 1) <= FWD_RTOL
     assert abs(gnorms["bf16"] / gnorms[None] - 1) <= GRAD_RTOL
 
@@ -287,7 +287,7 @@ def test_recipe_skips_on_nonfinite_batch():
     """A NaN batch must not move params, optimizer state, or the AdamW step
     counter — the scaler backs off and reports the skip."""
     key = jax.random.PRNGKey(0)
-    params = espnet.init_params(key, num_classes=4)
+    params = espnet.init_params(key, num_classes=5)
     state0 = train_recipes.init_state(params)
     batch = _seg_batch(jax.random.PRNGKey(1))
     batch["image"] = batch["image"].at[0, 0, 0, 0].set(jnp.nan)

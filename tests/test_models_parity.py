@@ -16,18 +16,22 @@ from numpy.testing import assert_allclose
 from repro.models import enet, espnet
 
 _HW = 16   # divisible by 8: both nets downsample 3x and upsample back
+# five ESPNet classes: the decoder's ESP splits them over its five branches
+_CLASSES = {"enet": 4, "espnet": 5}
 
 
 @pytest.fixture(scope="module")
 def enet_setup():
-    params = enet.init_params(jax.random.PRNGKey(0), num_classes=4)
+    params = enet.init_params(jax.random.PRNGKey(0),
+                              num_classes=_CLASSES["enet"])
     x = jax.random.normal(jax.random.PRNGKey(1), (1, _HW, _HW, 3))
     return params, x
 
 
 @pytest.fixture(scope="module")
 def espnet_setup():
-    params = espnet.init_params(jax.random.PRNGKey(2), num_classes=4)
+    params = espnet.init_params(jax.random.PRNGKey(2),
+                                num_classes=_CLASSES["espnet"])
     x = jax.random.normal(jax.random.PRNGKey(3), (1, _HW, _HW, 3))
     return params, x
 
@@ -44,7 +48,7 @@ def test_forward_three_way_parity(which, enet_setup, espnet_setup):
     model, (params, x) = ((enet, enet_setup) if which == "enet"
                           else (espnet, espnet_setup))
     y_dec, y_naive, y_pal = _forwards(model, params, x)
-    assert y_dec.shape == (1, _HW, _HW, 4)
+    assert y_dec.shape == (1, _HW, _HW, _CLASSES[which])
     # batch norm over a tiny batch amplifies fp32 accumulation-order noise
     # through the depth of the net (per-op exactness is pinned at 1e-5 in
     # test_kernels/test_gradients) — bound the *relative* error so a real

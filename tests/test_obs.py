@@ -19,11 +19,11 @@ from jax.profiler import ProfileData
 from repro import obs
 from repro.launch import train_recipes
 from repro.launch.serve_gen import GenServer
-from repro.models import dcgan, enet
+from repro.models import dcgan, enet, espnet
 
 
 def _scopes(hlo: str) -> set[str]:
-    return set(re.findall(r"(?:engine|layout|grad|train)\.[a-z_]+", hlo))
+    return set(re.findall(r"(?:engine|layout|grad|train|esp)\.[a-z_]+", hlo))
 
 
 @pytest.fixture(scope="module")
@@ -44,6 +44,18 @@ def test_enet_forward_names_every_engine_and_layout_pass(enet_params,
             obs.LAYOUT_PARITY_INTERLEAVE} <= names
     if backend == "pallas":
         assert {obs.LAYOUT_PAD, obs.LAYOUT_CROP} <= names
+
+
+def test_espnet_forward_names_its_merges_and_reinforcement():
+    """The ESP modules' merges and the input reinforcement carry their
+    ``esp.*`` scopes, beside the engines'."""
+    params = espnet.init_params(jax.random.PRNGKey(0), num_classes=5)
+    x = jnp.zeros((1, 32, 64, 3), jnp.float32)
+    hlo = jax.jit(espnet.forward).lower(params, x).as_text(debug_info=True)
+    assert {obs.ESP_MERGE, obs.ESP_REINFORCE} | set(obs.ENGINES) \
+        <= _scopes(hlo)
+    assert "esp.merge/concatenate" in hlo
+    assert "esp.reinforce/reduce_window" in hlo
 
 
 def test_enet_train_step_names_gradients_loss_and_optimizer(enet_params):

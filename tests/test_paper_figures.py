@@ -23,7 +23,7 @@ from repro.core import cycle_model as cm
 from repro.core.enet_spec import (
     dilated_layer_sets, enet_512_layers, transposed_layer_sets,
 )
-from repro.core.espnet_spec import espnet_512_layers
+from repro.core.espnet_spec import espnet_layers
 from repro.core.gen_spec import dcgan_layers, unet_decoder_layers
 
 # the benchmarks package lives at the repo root (pytest's pythonpath only
@@ -44,7 +44,7 @@ def enet():
 
 @pytest.fixture(scope="module")
 def espnet():
-    return espnet_512_layers()
+    return espnet_layers()
 
 
 # ------------------------------------------------------------- headline ---
@@ -127,29 +127,38 @@ def test_fig12_transposed_bands(enet):
 # -------------------------------------------------------- ESPNet workload ---
 
 def test_espnet_is_dilated_dominated(espnet):
-    """The spatial pyramid makes ESPNet even more dilated-heavy than ENet."""
+    """The spatial pyramid makes ESPNet even more dilated-heavy than ENet
+    (96% of the ideal-dense cycles); its 2x2 upsamplers on the class
+    channels are a small share (1.2%)."""
     rep = cm.report(espnet)
-    assert rep["share_dilated_pct"] >= 80.0
-    assert rep["share_transposed_pct"] >= 3.0
+    assert rep["share_dilated_pct"] >= 90.0
+    assert 0.5 <= rep["share_transposed_pct"] <= 3.0
 
 
 def test_espnet_overall_speedup(espnet):
+    """Dilated work at D = 15 in every module lifts the whole-net speed-up
+    above ENet's (15.8x ideal dense, 22.0x naive)."""
     rep = cm.report(espnet)
-    assert 7.5 <= rep["overall_speedup"] <= 10.0
-    assert 8.0 <= rep["speedup_vs_naive"] <= 11.0
+    assert 14.0 <= rep["overall_speedup"] <= 18.0
+    assert 19.0 <= rep["speedup_vs_naive"] <= 25.0
 
 
 def test_espnet_dilated_bands(espnet):
-    """Small mixed rates (2/4/8) sample the top of the Fig. 11 band, and the
-    strided down-ESP branches go through the output-class schedule."""
+    """Every module runs the whole band D = 1, 3, 7, 15 on narrow branches
+    (12 and 25 channels), which read 0.66-0.76 of ideal sparse, below
+    ENet's Fig. 11 band; the branches are stride 1 after the DownSamplerB's
+    strided reduce."""
     effs = {}
     for D, ls in dilated_layer_sets(espnet).items():
-        assert any(l.stride == 2 for l in ls)       # strided branch present
+        assert all(l.stride == 1 for l in ls)
+        assert len(ls) == 13                        # one per ESP module
         effs[D] = (sum(cm.cycles_ideal_sparse(l) for l in ls)
                    / sum(cm.cycles_our_decomposed(l) for l in ls))
-    assert set(effs) == {1, 3, 7}
-    assert all(0.90 <= e <= 0.99 for e in effs.values())
-    assert effs[1] > effs[3] > effs[7]
+    assert set(effs) == {1, 3, 7, 15}
+    assert all(0.60 <= e <= 0.80 for e in effs.values())
+    assert effs[1] > effs[3] > effs[7] > effs[15]
+    assert [l.name for l in espnet if l.stride == 2 and l.kind == "conv"] \
+        == ["level1", "l2.0.reduce3x3s2", "l3.0.reduce3x3s2"]
 
 
 # ------------------------------------------- generative decoder workloads ---
