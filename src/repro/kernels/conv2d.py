@@ -8,13 +8,31 @@ Rectangular kernels (``kh != kw`` — ENet's 5x1/1x5 asymmetric pair) are
 first-class: the tap loops, pads and halo are all per-dim.
 
 Tiling (per grid step): one batch element, ``TH`` output rows x full output
-width, one ``TC``-wide ``Cout`` tile.  The padded input is first split into
-``stride**2`` phase planes (a layout op, the identity for ``stride == 1``),
-so every tap of a strided conv reads a unit-stride window of one plane.  The
-row halo (``(kh - 1) // stride`` phase rows) is assembled *without
+width, one ``TC``-wide ``Cout`` tile.  The row halo is assembled *without
 overlapping BlockSpecs* by passing the input twice — the current row tile
-and the next row tile — and concatenating in VMEM (standard Pallas halo
-idiom).
+and the next row tile (standard Pallas halo idiom).
+
+A strided conv (``stride > 1``) has two forms, which give the same bits:
+
+* **strided loads** (``_conv_kernel_strided``): the blocks are ``s * TH``
+  rows of the padded NHWC input, and tap ``(dy, dx)`` is loaded straight
+  from the block refs with ``pl.ds(start, size, stride=s)`` — its last
+  ``dy // s`` rows from the next row tile.  Mosaic takes a strided *ref*
+  load, where it refuses a strided slice of a loaded *value*, but only of
+  fp32 data with at most 128 channels (:func:`_strided_loads_lower`).
+* **phase planes** (``_conv_kernel``): the padded input is first split
+  into ``s**2`` phase planes (a layout op), so every tap reads a
+  unit-stride window of one plane; the halo is ``(kh - 1) // s`` phase
+  rows, concatenated in VMEM.
+
+Forward-only use — the primal of the custom VJPs, which is what a jitted
+inference forward runs — takes the strided loads where they lower: the
+phase split of a 3-channel image is a lane-sparse transpose, the largest
+XLA op of a segmentation frame.  The VJPs' fwd rules, and the strided dense
+conv inside the transposed conv's backward, keep the phase planes: with
+strided loads on those paths too, the ENet-512 train step measured 11%
+slower on a TPU v5e (397.7 -> 442.5 ms), a regression not yet placed.  At
+``stride == 1`` the two forms coincide, and the phase split is the identity.
 
 An optional fused epilogue (:mod:`repro.kernels.epilogue`, DESIGN.md §7) —
 folded BN scale/shift, PReLU, residual add — is applied to the fp32
@@ -22,13 +40,14 @@ accumulator tile while it is still in VMEM, removing up to three elementwise
 HBM passes per convolution.
 
 VMEM per step ~ x_tile(2 * s*s * TH * Wq * Cin) + w(kh*kw*Cin*TC) +
-out(TH*W*TC), each padded to the (sublane, 128-lane) register tile; the
-kernel compiles with ``tiling_policy.VMEM_LIMIT_BYTES`` of scoped VMEM.  The
-grid runs the row stream innermost with ``dimension_semantics`` declared, so
-Mosaic's pipeliner double-buffers the input halo pair (next tile's DMA
-overlaps the current tile's MXU work) while the weight tile stays resident
-for a whole ``Cout``-tile pass; ``tiling_policy.footprint_bytes`` mirrors
-exactly these blocks when the autotuner scores candidates (DESIGN.md §12).
+out(TH*W*TC) in either form, each padded to the (sublane, 128-lane) register
+tile; the kernel compiles with ``tiling_policy.VMEM_LIMIT_BYTES`` of scoped
+VMEM.  The grid runs the row stream innermost with ``dimension_semantics``
+declared, so Mosaic's pipeliner double-buffers the input halo pair (next
+tile's DMA overlaps the current tile's MXU work) while the weight tile stays
+resident for a whole ``Cout``-tile pass; ``tiling_policy.footprint_bytes``
+mirrors these blocks, the larger of the two forms' sets, when the autotuner
+scores candidates (DESIGN.md §12).
 
 Mixed precision (DESIGN.md §12): bf16 inputs accumulate in fp32 — every tap
 GEMM issues with ``preferred_element_type=jnp.float32``, the fused epilogue
@@ -55,38 +74,81 @@ from repro.kernels.util import resolve_interpret
 _NO_EP = EpilogueSpec()
 
 
-def _conv_kernel(x_cur, x_nxt, w, *rest, spec: EpilogueSpec, th: int,
-                 kh: int, kw: int, stride: int, w_out: int, halo: int):
-    """One (batch, row-tile, cout-tile) grid step.
-
-    The input arrives split into ``stride**2`` phase planes, so tap
-    ``(dy, dx)`` of a strided conv is a unit-stride window of plane
-    ``(dy % s, dx % s)`` at offset ``(dy // s, dx // s)`` — Mosaic lowers
-    unit-stride value slices but not strided ones.
-    """
-    out = rest[-1]
-    ep_refs = rest[:-1]
-    s = stride
-    # assemble the window: TH phase rows + halo rows from the next tile
-    xw = x_cur[0]
-    if halo > 0:
-        xw = jnp.concatenate([xw, x_nxt[0][:, :halo]], axis=1)
-    cin = xw.shape[-1]
-    acc = jnp.zeros((th * w_out, out.shape[-1]), jnp.float32)
+def _accumulate(tap, w, *, kh: int, kw: int, rows: int, tc: int):
+    """Sum the ``kh*kw`` tap GEMMs in tap order, in fp32: ``tap(dy, dx)``
+    is the (TH, W_out, Cin) input window that meets ``w[dy, dx]``."""
+    acc = jnp.zeros((rows, tc), jnp.float32)
     for dy in range(kh):
         for dx in range(kw):
-            oy, ox = dy // s, dx // s
-            rows = xw[(dy % s) * s + dx % s, oy:oy + th, ox:ox + w_out, :]
+            xs = tap(dy, dx)
             acc += jax.lax.dot_general(
-                rows.reshape(th * w_out, cin), w[dy, dx],
+                xs.reshape(rows, xs.shape[-1]), w[dy, dx],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
+    return acc
+
+
+def _store(acc, out, ep_refs, spec: EpilogueSpec, th: int, w_out: int):
+    """Apply the fused epilogue to the fp32 tile and write it out."""
     if not spec.empty:
         args = tuple(r[0] if name == "residual" else r[...]
                      for name, r in zip(spec.slots, ep_refs))
         acc = apply_tile(spec, acc, args, flat=th * w_out)
     out[0] = acc.reshape(th, w_out, out.shape[-1]).astype(out.dtype)
+
+
+def _conv_kernel(x_cur, x_nxt, w, *rest, spec: EpilogueSpec, th: int,
+                 kh: int, kw: int, stride: int, w_out: int, halo: int):
+    """One (batch, row-tile, cout-tile) grid step, phase-plane form.
+
+    The input arrives split into ``stride**2`` phase planes, so tap
+    ``(dy, dx)`` of a strided conv is a unit-stride window of plane
+    ``(dy % s, dx % s)`` at offset ``(dy // s, dx // s)``.  Every conv at
+    stride 1 runs this body (one plane); a strided one runs it under a
+    VJP's fwd rule and inside the transposed conv's backward (module doc).
+    """
+    s = stride
+    # assemble the window: TH phase rows + halo rows from the next tile
+    xw = x_cur[0]
+    if halo > 0:
+        xw = jnp.concatenate([xw, x_nxt[0][:, :halo]], axis=1)
+
+    def tap(dy, dx):
+        oy, ox = dy // s, dx // s
+        return xw[(dy % s) * s + dx % s, oy:oy + th, ox:ox + w_out, :]
+
+    acc = _accumulate(tap, w, kh=kh, kw=kw, rows=th * w_out,
+                      tc=rest[-1].shape[-1])
+    _store(acc, rest[-1], rest[:-1], spec, th, w_out)
+
+
+def _conv_kernel_strided(x_cur, x_nxt, w, *rest, spec: EpilogueSpec,
+                         th: int, kh: int, kw: int, stride: int, w_out: int):
+    """One (batch, row-tile, cout-tile) grid step, strided-load form.
+
+    The blocks are ``s * TH`` rows of the padded input.  Output row ``t`` of
+    tap ``(dy, dx)`` reads padded row ``s*t + dy``: rows ``t < TH - dy//s``
+    lie in this tile, the last ``dy // s`` at row ``dy % s`` of the next
+    tile on.  Both parts are strided loads from the refs, so the tap window
+    holds the very values the phase-plane form reads, and the GEMMs give
+    the same bits.
+    """
+    s = stride
+
+    def tap(dy, dx):
+        n_nxt = dy // s
+        cols = pl.ds(dx, w_out, stride=s)
+        parts = []
+        if n_nxt < th:
+            parts.append(x_cur[0, pl.ds(dy, th - n_nxt, stride=s), cols])
+        if n_nxt:
+            parts.append(x_nxt[0, pl.ds(dy % s, n_nxt, stride=s), cols])
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
+
+    acc = _accumulate(tap, w, kh=kh, kw=kw, rows=th * w_out,
+                      tc=rest[-1].shape[-1])
+    _store(acc, rest[-1], rest[:-1], spec, th, w_out)
 
 
 @functools.partial(
@@ -142,9 +204,21 @@ def _chan_operand(v: jax.Array, cout: int, cout_p: int) -> jax.Array:
             1, cout_p)
 
 
+def _strided_loads_lower(dtype, cin: int) -> bool:
+    """Whether Mosaic lowers the strided-load form: it takes a strided ref
+    load only of 32-bit data, from a block whose channels fit one 128-lane
+    tile (refused on a TPU v5e compile: "Strided load with non 32-bit
+    data", "The last dim size is not 128").  Elsewhere the phase planes
+    run."""
+    return jnp.dtype(dtype).itemsize == 4 and cin <= tiling_policy.LANES
+
+
 def _conv2d_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
                 stride: int, pads: tuple[tuple[int, int], tuple[int, int]],
-                th: int, tc: int, interpret: bool) -> jax.Array:
+                th: int, tc: int, interpret: bool, *,
+                strided_loads: bool) -> jax.Array:
+    """The conv as one ``pallas_call``; ``strided_loads`` picks the form of
+    a strided conv (module doc) and is moot at ``stride == 1``."""
     n, h, w_in, cin = x.shape
     kh, kw, _, cout = w.shape
     s = stride
@@ -174,22 +248,32 @@ def _conv2d_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
              (pw[0], max(s * cols_q - w_in - pw[0], 0)), (0, 0)),
         )[:, :s * rows_q, :s * cols_q, :]
         wp = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, cout_p - cout)))
-    # (N, s*Rq, s*Cq, Cin) -> (N, s*s, Rq, Cq, Cin): a layout op (identity
-    # for s == 1) that turns every strided tap into a unit-stride window
-    with jax.named_scope(obs.LAYOUT_PHASE_SPLIT):
-        xp = xp.reshape(n, rows_q, s, cols_q, s, cin).transpose(
-            0, 2, 4, 1, 3, 5)
-        xp = xp.reshape(n, s * s, rows_q, cols_q, cin)
+    if strided_loads and s > 1 and _strided_loads_lower(x.dtype, cin):
+        # (N, s*Rq, s*Cq, Cin) as it is: s*th padded rows a row tile
+        kernel = functools.partial(_conv_kernel_strided, spec=spec, th=th,
+                                   kh=kh, kw=kw, stride=s, w_out=w_out)
+        x_block = (1, s * th, s * cols_q, cin)
+        x_index = lambda b, i: (b, i, 0, 0)
+    else:
+        # (N, s*Rq, s*Cq, Cin) -> (N, s*s, Rq, Cq, Cin): a layout op
+        # (identity for s == 1) that turns every strided tap into a
+        # unit-stride window
+        with jax.named_scope(obs.LAYOUT_PHASE_SPLIT):
+            xp = xp.reshape(n, rows_q, s, cols_q, s, cin).transpose(
+                0, 2, 4, 1, 3, 5)
+            xp = xp.reshape(n, s * s, rows_q, cols_q, cin)
+        kernel = functools.partial(_conv_kernel, spec=spec, th=th, kh=kh,
+                                   kw=kw, stride=s, w_out=w_out, halo=halo)
+        x_block = (1, s * s, th, cols_q, cin)
+        x_index = lambda b, i: (b, 0, i, 0, 0)
 
     # grid order (batch, cout tile, row tile): the row stream is innermost,
     # so the pipeline double-buffers consecutive input row tiles (the halo
     # pair advances by one block per step) while the weight tile's block
     # index is unchanged across the whole inner stream and stays resident
     grid = (n, n_cout_tiles, n_row_tiles)
-    x_spec_cur = pl.BlockSpec((1, s * s, th, cols_q, cin),
-                              lambda b, c, i: (b, 0, i, 0, 0))
-    x_spec_nxt = pl.BlockSpec((1, s * s, th, cols_q, cin),
-                              lambda b, c, i: (b, 0, i + 1, 0, 0))
+    x_spec_cur = pl.BlockSpec(x_block, lambda b, c, i: x_index(b, i))
+    x_spec_nxt = pl.BlockSpec(x_block, lambda b, c, i: x_index(b, i + 1))
     w_spec = pl.BlockSpec((kh, kw, cin, tc), lambda b, c, i: (0, 0, 0, c))
     out_spec = pl.BlockSpec((1, th, w_out, tc), lambda b, c, i: (b, i, 0, c))
 
@@ -211,8 +295,7 @@ def _conv2d_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
             ep_specs.append(pl.BlockSpec((1, tc), lambda b, c, i: (0, c)))
 
     out = pl.pallas_call(
-        functools.partial(_conv_kernel, spec=spec, th=th, kh=kh, kw=kw,
-                          stride=s, w_out=w_out, halo=halo),
+        kernel,
         grid=grid,
         in_specs=[x_spec_cur, x_spec_nxt, w_spec, *ep_specs],
         out_specs=out_spec,
@@ -232,7 +315,20 @@ def _conv2d_raw(x: jax.Array, w: jax.Array, eps: tuple, spec: EpilogueSpec,
 def _conv2d_impl(x: jax.Array, w: jax.Array, stride: int,
                  pads: tuple[tuple[int, int], tuple[int, int]],
                  th: int, tc: int, interpret: bool) -> jax.Array:
-    return _conv2d_raw(x, w, (), _NO_EP, stride, pads, th, tc, interpret)
+    # the primal: what runs where no gradient is taken
+    return _conv2d_raw(x, w, (), _NO_EP, stride, pads, th, tc, interpret,
+                       strided_loads=True)
+
+
+@functools.partial(jax.jit, static_argnames=("stride", "th", "tc",
+                                             "interpret"))
+def conv2d_phase_planes(x: jax.Array, w: jax.Array, *, stride: int, th: int,
+                        tc: int, interpret: bool) -> jax.Array:
+    """A VALID dense conv in the phase-plane form, for a conv that a
+    backward rule runs (the transposed conv's input-gradient): there it
+    keeps the form that the training step has measured (module doc)."""
+    return _conv2d_raw(x, w, (), _NO_EP, stride, ((0, 0), (0, 0)), th, tc,
+                       interpret, strided_loads=False)
 
 
 # ---------------------------------------------------------------------------
@@ -246,7 +342,9 @@ _conv2d_vjp = jax.custom_vjp(_conv2d_impl, nondiff_argnums=(2, 3, 4, 5, 6))
 
 
 def _conv2d_fwd(x, w, stride, pads, th, tc, interpret):
-    return _conv2d_impl(x, w, stride, pads, th, tc, interpret), (x, w)
+    y = _conv2d_raw(x, w, (), _NO_EP, stride, pads, th, tc, interpret,
+                    strided_loads=False)
+    return y, (x, w)
 
 
 @jax.named_scope(obs.GRAD_DX)
@@ -299,7 +397,8 @@ _conv2d_vjp.defvjp(_conv2d_fwd, _conv2d_bwd)
 # ---------------------------------------------------------------------------
 
 def _conv2d_ep_impl(x, w, eps, spec, stride, pads, th, tc, interpret):
-    return _conv2d_raw(x, w, eps, spec, stride, pads, th, tc, interpret)
+    return _conv2d_raw(x, w, eps, spec, stride, pads, th, tc, interpret,
+                       strided_loads=True)
 
 
 _conv2d_ep_vjp = jax.custom_vjp(_conv2d_ep_impl,
@@ -307,7 +406,8 @@ _conv2d_ep_vjp = jax.custom_vjp(_conv2d_ep_impl,
 
 
 def _conv2d_ep_fwd(x, w, eps, spec, stride, pads, th, tc, interpret):
-    y = _conv2d_ep_impl(x, w, eps, spec, stride, pads, th, tc, interpret)
+    y = _conv2d_raw(x, w, eps, spec, stride, pads, th, tc, interpret,
+                    strided_loads=False)
     return y, (x, w, eps)
 
 

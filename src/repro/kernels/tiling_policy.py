@@ -126,7 +126,8 @@ def footprint_bytes(kind: str, x_shape, w_shape, th: int, tc: int, *,
     weight tile + output tile (x2 for the pipeline), epilogue operands, the
     assembled halo window and the fp32 accumulator, each padded to the
     register tile (:func:`padded_bytes`).  Dilated geometries are scored as
-    the dense kernel on the phase-batched layout they actually run.
+    the dense kernel on the phase-batched layout they actually run; a
+    strided dense conv as the larger of its two forms (``conv2d.py``).
     """
     f32 = jnp.float32
     if kind == "dilated":
@@ -140,6 +141,13 @@ def footprint_bytes(kind: str, x_shape, w_shape, th: int, tc: int, *,
         th_e = max(min(th, h_out), halo)
         tc_e = min(tc, cout)
         cols = w_out + (kw - 1) // s
+        # the phase-plane form's blocks and window.  The table keys on
+        # geometry, not on the form, and a strided conv may run the
+        # strided-load form instead: its blocks of s*th padded rows of
+        # s*cols columns take no more VMEM than s*s planes of th rows of
+        # cols columns (s times cols rounded up to the sublane tile is at
+        # least s*cols rounded up), and it assembles no window.  So this
+        # set is the larger of the two forms'
         x_block = padded_bytes((s * s, th_e, cols, cin), dtype)
         window = padded_bytes((s * s, th_e + halo, cols, cin), dtype) \
             if halo else 0

@@ -285,15 +285,15 @@ def _tconv_fwd(x, w, s, p_lo, output_padding, th, tc, interpret):
 
 def _tconv_bwd(s, p_lo, output_padding, th, tc, interpret, res, g):
     from repro.core import adjoints
-    from repro.kernels.conv2d import conv2d as _dense_conv
+    from repro.kernels.conv2d import conv2d_phase_planes
 
     x, w = res
     k = w.shape[0]
     p_hi = p_lo + output_padding
 
     def conv_fn(gp, wf, stride):
-        return _dense_conv(gp, wf, stride=stride, padding="VALID",
-                           th=th, tc=tc, interpret=interpret)
+        return conv2d_phase_planes(gp, wf, stride=stride, th=th, tc=tc,
+                                   interpret=interpret)
 
     dx = adjoints.tconv_dx(g, w, s, p_lo, p_hi, conv_fn)
     dw = adjoints.tconv_dw(x, g, k, s, p_lo, p_hi)

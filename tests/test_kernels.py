@@ -4,13 +4,17 @@ All kernels run in interpret mode (CPU executes the kernel body; BlockSpec
 tiling and grid semantics are fully exercised).
 """
 
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jcore
 from numpy.testing import assert_allclose
 
-from repro.kernels import ops, ref
+from repro.kernels import conv2d as dense, ops, ref
+from repro.kernels.epilogue import EpilogueSpec, apply_reference, pack_args
 
 
 def _pair(key, xshape, wshape, dtype):
@@ -60,6 +64,120 @@ def test_conv2d_kernel_rectangular(ks, stride):
     want = ref.conv2d_ref(x, wt, stride=stride, padding="SAME")
     assert got.shape == want.shape
     assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------- strided dense conv: the two forms ---
+
+def _kernels(fn, *args) -> collections.Counter:
+    """The Pallas kernels in ``fn``'s jaxpr, by the name of their body; a
+    dense conv's phase-plane body is ``planes`` where it holds more than
+    one plane (a strided conv) and ``unit`` at stride 1."""
+    found = collections.Counter()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                body = eqn.params["jaxpr"]
+                name = body.debug_info.func_name
+                if name == "_conv_kernel":
+                    name = "planes" if body.invars[0].aval.shape[1] > 1 \
+                        else "unit"
+                found[name] += 1
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):
+                    if isinstance(sub, jcore.ClosedJaxpr):
+                        walk(sub.jaxpr)
+                    elif isinstance(sub, jcore.Jaxpr):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+#: (id, x shape, (kh, kw, cin, cout), stride, ((ph_lo, ph_hi), (pw_lo, pw_hi)))
+STRIDED_CASES = [
+    ("enet-stem-odd", (1, 17, 15, 3), (3, 3, 3, 13), 2, ((1, 1), (1, 1))),
+    ("enet-stem-even", (1, 16, 16, 3), (3, 3, 3, 13), 2, ((1, 1), (1, 1))),
+    ("espnet-level1-wide", (1, 16, 40, 3), (3, 3, 3, 16), 2,
+     ((1, 1), (1, 1))),
+    ("reduce-2x2s2-cin16", (1, 16, 16, 16), (2, 2, 16, 16), 2,
+     ((0, 0), (0, 0))),
+    ("reduce-3x3s2-cin19", (1, 16, 24, 19), (3, 3, 19, 12), 2,
+     ((1, 1), (1, 1))),
+    ("rect-5x1s2", (1, 14, 11, 3), (5, 1, 3, 5), 2, ((2, 2), (0, 0))),
+]
+
+_BN_PRELU_RES = EpilogueSpec(bn=True, prelu=True, residual="post_act")
+
+
+@pytest.mark.parametrize("epilogue", [False, True], ids=["plain", "bn-prelu-res"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("xs,ws,s,pads", [c[1:] for c in STRIDED_CASES],
+                         ids=[c[0] for c in STRIDED_CASES])
+def test_strided_loads_equal_phase_planes(xs, ws, s, pads, dtype, epilogue):
+    """The strided-load form gives the phase-plane form's bits, and both
+    match the oracle.  Mosaic takes strided loads of 32-bit data only, so a
+    bf16 conv keeps the phase planes in either form."""
+    x, w = _pair(sum(xs) + sum(ws), xs, ws, dtype)
+    cout = ws[3]
+    spec, eps = dense._NO_EP, ()
+    if epilogue:
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(cout), 3)
+        y_ref = ref.conv2d_ref(x, w, stride=s, padding=list(pads))
+        spec = _BN_PRELU_RES
+        eps = pack_args(spec, scale=1 + 0.1 * jax.random.normal(k1, (cout,)),
+                        shift=jax.random.normal(k2, (cout,)),
+                        alpha=jnp.full((1,), 0.25),
+                        residual=jax.random.normal(k3, y_ref.shape, dtype))
+
+    def form(strided_loads):
+        return lambda x, w: dense._conv2d_raw(
+            x, w, eps, spec, s, pads, 4, 128, True,
+            strided_loads=strided_loads)
+
+    strided, planes = form(True), form(False)
+    want_body = "_conv_kernel_strided" if dtype == jnp.float32 else "planes"
+    assert _kernels(strided, x, w) == {want_body: 1}
+    assert _kernels(planes, x, w) == {"planes": 1}
+    got = strided(x, w)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(planes(x, w), np.float32))
+    want = apply_reference(spec, ref.conv2d_ref(x, w, stride=s,
+                                                padding=list(pads)), eps)
+    assert got.shape == want.shape
+    assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                    **_tol(dtype))
+
+
+def test_forward_takes_strided_loads_and_training_keeps_phase_planes():
+    """Forward-only use lowers the strided-load kernel; under ``jax.grad``
+    the fwd rules and the backward rules lower only the phase planes, so a
+    training step keeps the form it has been measured with."""
+    from repro.models import enet
+
+    x, w = _pair(3, (1, 16, 16, 3), (3, 3, 3, 13), jnp.float32)
+    conv = lambda x, w: ops.conv2d(x, w, stride=2, padding="SAME")
+    loss = lambda x, w: jnp.sum(conv(x, w) ** 2)
+    assert _kernels(jax.jit(conv), x, w) == {"_conv_kernel_strided": 1}
+    grad = _kernels(jax.jit(jax.grad(loss, argnums=(0, 1))), x, w)
+    assert grad["planes"] == 1 and "_conv_kernel_strided" not in grad
+
+    params = enet.init_params(jax.random.PRNGKey(0), num_classes=5)
+    img = jnp.zeros((1, 32, 32, 3), jnp.float32)
+    fwd = _kernels(lambda p, x: enet.forward(p, x, backend="pallas"),
+                   params, img)
+    # the stem and the two downsampling bottlenecks' 2x2 reduce
+    assert fwd["_conv_kernel_strided"] == 3 and "planes" not in fwd
+    enet_loss = lambda p, x: jnp.sum(enet.forward(p, x, backend="pallas"))
+    grad = _kernels(jax.grad(enet_loss), params, img)
+    assert grad["planes"] >= 3 and "_conv_kernel_strided" not in grad
+
+    # the transposed conv's input-gradient is a strided dense conv
+    xt, wt = _pair(4, (1, 8, 8, 6), (3, 3, 6, 9), jnp.float32)
+    tloss = lambda x, w: jnp.sum(ops.transposed_conv2d(x, w, stride=2) ** 2)
+    grad = _kernels(jax.grad(tloss, argnums=(0, 1)), xt, wt)
+    assert grad["planes"] == 1 and grad["_tconv_kernel"] == 1
+    assert "_conv_kernel_strided" not in grad
 
 
 # --------------------------------------------------------- dilated conv ---

@@ -356,6 +356,30 @@ def test_footprint_counts_register_tile_padding():
     assert small <= tp.VMEM_BUDGET_BYTES
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_footprint_covers_both_strided_conv_forms(dtype):
+    """The ENet-512 stem (3x3/s2, 3 channels, 256 output cols) runs as phase
+    planes under a gradient and with strided loads in an inference forward;
+    the tile table keys on geometry, so its score covers the blocks of
+    either form, counted here by hand."""
+    geom = dict(x_shape=(1, 512, 512, 3), w_shape=(3, 3, 3, 13), th=8,
+                tc=128, stride=2, dtype=dtype)
+    b = tp.itemsize(dtype)
+    sub = 8 if dtype == jnp.float32 else 16
+    pad = lambda n: -(-n // sub) * sub
+    lane = 128 * b
+    # weight (3, 3, 3->sub, 13->128), output (8, 256, 13->128), fp32 acc
+    rest = 2 * (9 * pad(3) * lane + 8 * 256 * lane) + 8 * 256 * 128 * 4
+    # phase planes: a cur/next pair of (4 planes, 8 rows, 257 cols) blocks,
+    # double-buffered, and the (4, 9, 257) window they are joined into
+    planes = 2 * 2 * (4 * 8 * pad(257) * lane) + 4 * 9 * pad(257) * lane
+    # strided loads: a cur/next pair of (16 rows, 514 cols) blocks
+    rows = 2 * 2 * (16 * pad(514) * lane)
+    fp = tp.footprint_bytes("dense", **geom)
+    assert fp >= planes + rest
+    assert fp >= rows + rest
+
+
 def test_rank_marks_over_budget_candidates_inf():
     cands = [(4, 64), (8, 64), (8, 128)]
     ranked = tp.rank("dense", **_POLICY_GEOM, cands=cands, vmem_budget=1)
